@@ -4,6 +4,9 @@
   PYTHONPATH=src python -m benchmarks.run --only tables123,procmodel
   PYTHONPATH=src python -m benchmarks.run --json out.json   # + JSON dump
   PYTHONPATH=src python -m benchmarks.run --profile /tmp/tr  # + traces
+
+shard_bench needs four devices: on a CPU host run the driver under
+XLA_FLAGS=--xla_force_host_platform_device_count=4.
 """
 
 import argparse
@@ -112,4 +115,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

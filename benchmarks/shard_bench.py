@@ -18,13 +18,16 @@ and records median group-launch wall time for a 1-request and an
 same latents — engines bind identical checkpoints, so mesh configs
 must reproduce the single-device images).
 
-Device counts are fixed at jax init, so the measured grid runs in ONE
-worker subprocess under ``--xla_force_host_platform_device_count=4``;
-the parent (``main``/``run``) just parses its JSON.  Results go to
-BENCH_shard.json for the cross-PR trajectory.
+The grid runs in the calling process over its first four devices: the
+chips of a four-chip host, or on a CPU host the virtual devices that
+``XLA_FLAGS=--xla_force_host_platform_device_count=4`` creates (the
+device count is fixed when jax initialises, so set it in the
+environment).  Results go to BENCH_shard.json for the cross-PR
+trajectory.
 
-  PYTHONPATH=src python -m benchmarks.shard_bench              # full
-  PYTHONPATH=src python -m benchmarks.shard_bench --nets gpgan,voxgan
+  PYTHONPATH=src XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+      python -m benchmarks.shard_bench              # full
+  ... python -m benchmarks.shard_bench --nets gpgan,voxgan
 """
 
 from __future__ import annotations
@@ -32,9 +35,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
-import tempfile
 
 ALL_NETS = ("dcgan", "sngan", "artgan", "gpgan", "mde", "fst", "voxgan")
 CONFIGS = (("dp1", 1, 1), ("dp4", 4, 1), ("dp2xmp2", 2, 2),
@@ -43,17 +44,18 @@ OUT_JSON = "BENCH_shard.json"
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-# ---------------------------------------------------------------------------
-# worker: runs inside the 4-device subprocess
-# ---------------------------------------------------------------------------
-
-def _worker(nets, iters, reduced, out_path):
+def sweep(nets=ALL_NETS, iters=3, reduced=False, out=OUT_JSON,
+          report=None):
     import jax
     import numpy as np
     from repro.kernels.autotune import measure
     from repro.launch.serve_gen import GenServer, reduced_specs
 
-    assert jax.device_count() >= 4, jax.devices()
+    if jax.device_count() < 4:
+        raise RuntimeError(
+            f"shard_bench needs 4 devices, have {jax.device_count()} "
+            "(on a CPU host set XLA_FLAGS="
+            "--xla_force_host_platform_device_count=4)")
     specs = reduced_specs() if reduced else None
     if reduced:
         nets = list(specs)
@@ -99,42 +101,6 @@ def _worker(nets, iters, reduced, out_path):
               f"{rec['launch_speedup_mesh_vs_dp']}x "
               f"parity={'OK' if rec['parity_ok'] else 'FAIL'}",
               file=sys.stderr)
-    with open(out_path, "w") as f:
-        json.dump(results, f, indent=1, sort_keys=True)
-
-
-# ---------------------------------------------------------------------------
-# parent: spawn the 4-device worker, collect, report
-# ---------------------------------------------------------------------------
-
-def sweep(nets=ALL_NETS, iters=3, reduced=False, out=OUT_JSON,
-          report=None, timeout=3600):
-    env = dict(
-        os.environ,
-        PYTHONPATH=(os.path.join(_REPO, "src") + os.pathsep +
-                    os.environ.get("PYTHONPATH", "")),
-        XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
-                   " --xla_force_host_platform_device_count=4"))
-    with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as tf:
-        tmp = tf.name
-    try:
-        cmd = [sys.executable, "-m", "benchmarks.shard_bench",
-               "--worker", "--out", tmp, "--nets", ",".join(nets),
-               "--iters", str(iters)]
-        if reduced:
-            cmd.append("--reduced")
-        proc = subprocess.run(cmd, env=env, cwd=_REPO, text=True,
-                              capture_output=True, timeout=timeout)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"shard_bench worker failed:\n{proc.stderr[-4000:]}")
-        with open(tmp) as f:
-            results = json.load(f)
-    finally:
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
 
     if report is not None:
         report.section("DP x MP serving grid — sharded plans vs "
@@ -174,15 +140,9 @@ def main(argv=None):
     ap.add_argument("--reduced", action="store_true",
                     help="dryrun-sized specs (ci smoke)")
     ap.add_argument("--out", default=OUT_JSON)
-    ap.add_argument("--worker", action="store_true",
-                    help=argparse.SUPPRESS)   # internal: inside 4-dev env
     args = ap.parse_args(argv)
-    nets = tuple(args.nets.split(","))
-    if args.worker:
-        _worker(nets, args.iters, args.reduced, args.out)
-        return
-    sweep(nets=nets, iters=args.iters, reduced=args.reduced,
-          out=args.out)
+    sweep(nets=tuple(args.nets.split(",")), iters=args.iters,
+          reduced=args.reduced, out=args.out)
 
 
 if __name__ == "__main__":

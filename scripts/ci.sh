@@ -5,18 +5,9 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-echo "== dev deps (hypothesis: property tests run natively; without it"
-echo "   the _hypothesis_compat fallback runner still executes them) =="
+echo "== dev deps =="
 python -m pip install -q -r requirements-dev.txt 2>/dev/null \
-  || echo "  (pip install skipped — offline; fallback runner active)"
-python - <<'PY'
-try:
-    import hypothesis
-    print(f"  hypothesis {hypothesis.__version__}: property tests native")
-except ModuleNotFoundError:
-    print("  hypothesis missing: property tests via _hypothesis_compat "
-          "fallback runner (they RUN, not skip)")
-PY
+  || echo "  (pip install skipped — offline)"
 
 echo "== tier-1 test suite =="
 python -m pytest -x -q
@@ -309,8 +300,9 @@ print(f"mesh serving gate OK: {len(nets)} nets parity <= 1e-5, "
       f"{n0} compiles closed over swap")
 PY
 
-echo "== DP x MP grid smoke (shard_bench on reduced specs, parity-gated"
-echo "   inside the 4-device worker) =="
+echo "== DP x MP grid smoke (shard_bench on reduced specs over 4 host"
+echo "   devices, parity-gated) =="
+XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=4" \
 python -m benchmarks.shard_bench --reduced --iters 1 \
   --out /tmp/BENCH_shard_smoke.json
 python - <<'PY'

@@ -467,14 +467,16 @@ def sd_deconv_paper(x: jax.Array, w: jax.Array, stride: IntPair,
 def conv_nd(x: jax.Array, w: jax.Array, stride=1,
             padding="SAME") -> jax.Array:
     """Plain channels-last cross-correlation, any rank (the op CNN
-    processors run)."""
+    processors run).  HIGHEST precision: f32 operands stay f32 on the
+    TPU's MXU (its default rounds them to bf16); bf16 is unaffected."""
     rank = w.ndim - 2
     s = _ntuple(stride, rank)
     if isinstance(padding, int):
         padding = [(padding, padding)] * rank
     return lax.conv_general_dilated(
         x, w, window_strides=s, padding=padding,
-        dimension_numbers=conv_dimension_numbers(rank))
+        dimension_numbers=conv_dimension_numbers(rank),
+        precision=lax.Precision.HIGHEST)
 
 
 def conv2d(x: jax.Array, w: jax.Array, stride: IntPair = 1,

@@ -8,8 +8,8 @@ One module owns every int8 helper in the repo:
   for the gradient-compression hop; they were promoted here so the
   inference path and the transport path share one rounding convention
   (symmetric, zero-point 0, clip to ±127 — so a zero stays exactly
-  zero, which is what lets the Pallas kernels' masked halo reads
-  zero-fill *in int8*).
+  zero, which is what keeps the Pallas kernels' zero-padded halo exact
+  *in int8*).
 * :func:`quantize_channelwise` — per-**channel** scales along one axis.
   This is the filter quantizer: :meth:`repro.sd.DeconvPlan.bind` calls
   it on the split (scale-folded) filters with ``axis=-1``, so every

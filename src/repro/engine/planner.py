@@ -267,6 +267,11 @@ class SDEngine:
                 bias=p["b"].astype(jnp.float32),
                 mesh=self.mesh if shards > 1 else None,
                 axis=self.mp_axis)
+            if (self.mesh is not None and shards == 1
+                    and not isinstance(bound.ws, jax.core.Tracer)):
+                # Replicated layers live on every mesh device too, so a
+                # launch moves no filter bytes between devices.
+                bound = bound.shard_put(self.mesh)
             if calib and layer.name in calib:
                 bound = bound.with_chain(
                     sx_in=calib[layer.name],
@@ -446,8 +451,11 @@ class SDEngine:
                      if plan.ws is not None and plan.dtype != "int8"
                      else jnp.float32)
             variants = [plan]
+            # The Winograd kernel does not lower with Mosaic (ROADMAP
+            # S7): on TPU only the direct kernel is a candidate.
             if (self.backend == "fused" and plan.backend == "fused"
-                    and plan.dtype != "int8" and supported(plan.kt)):
+                    and plan.dtype != "int8" and supported(plan.kt)
+                    and jax.default_backend() != "tpu"):
                 variants.append(dataclasses_replace(
                     plan, backend="winograd", layout="wino",
                     ws=transform_filters(plan.ws)))

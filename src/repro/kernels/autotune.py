@@ -13,7 +13,7 @@ This module provides:
 
 * :class:`ConvGeom` — the key: the *executed* stride-1 conv geometry
   ``(b, h, w, cin, cout, kt, s)`` where ``h/w`` are the P_I-padded
-  input sizes (the zero-copy kernels apply that pad in-kernel, but the
+  input sizes (the kernels' wrappers apply that pad, but the
   geometry — and therefore the cache key — is unchanged), ``cout``
   counts deconv output channels (oc units) and ``s`` is the in-kernel
   interleave factor (1 for the plain conv kernel).  ``tag`` names
@@ -201,13 +201,12 @@ class ConvGeom:
             crop_h=pk + pads[0][0], crop_w=pk + pads[1][0])
 
 
-def _divisor_tiles(c: int, prefer: tuple = (128, 64, 32, 16, 8)) -> List[int]:
-    """Channel tile candidates: the full depth plus MXU-friendly divisors."""
-    tiles = [c]
-    for t in prefer:
-        if t < c and c % t == 0:
-            tiles.append(t)
-    return tiles
+def lane_tiles(c: int) -> List[int]:
+    """Channel tile candidates Mosaic can lay out: the full depth plus
+    every multiple of 128 lanes that divides it.  Channels are the lane
+    (last) dim of the bands, filter blocks and output tiles, whose block
+    dims must be a multiple of 128 or the whole dim."""
+    return [c] + [t for t in (1024, 512, 256, 128) if t < c and c % t == 0]
 
 
 def _row_tile_options(oh: int) -> List[int]:
@@ -260,21 +259,24 @@ def _pick_th(geom: ConvGeom) -> int:
                key=lambda t: (_row_cost(oh, t), -t))
 
 
-# Per-launch VMEM budget for the footprint model: half the ~16 MiB core
-# VMEM, leaving headroom for double buffering and the bias block.
+# Per-launch VMEM budget for the footprint model: half the 16 MiB of
+# scoped VMEM a Mosaic kernel gets on v5e.  The model counts the
+# double-buffered blocks and the scratch at their padded (sublane,
+# 128-lane) tiles; Mosaic's temporaries for the loop bodies come on top
+# (the v5e compiler measured 19.05 MiB for an MDE up1 step this model
+# puts at 10.5 MiB), hence the 2x headroom.
 VMEM_BUDGET = 8 << 20
 
-# Filter-block sub-budget, kept from the pre-``tw`` heuristic so plan
-# keys/choices on narrow layers are stable (and asserted by tests).
-_FILTER_BUDGET = 2 << 20
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def vmem_plan_bytes(geom: ConvGeom, plan: KernelPlan) -> int:
     """VMEM footprint of one grid step: input band *including the
     (K_T - 1) halo and the residual-crop row*, filter block,
-    accumulator and interleaved output tile — the pre-``tw`` heuristic
-    only modelled the filter block, which is how full-width bands on
-    wide layers (artgan/fst/mde) blew past the real budget.
+    accumulator and interleaved output tile, each padded to its VMEM
+    tiles, with the pipelined blocks counted twice (double buffering).
 
     Dtype-aware: the band and filter block are stored at the operand
     itemsize (1 byte for int8 — 4x smaller tiles-side footprint, which
@@ -299,31 +301,49 @@ def vmem_plan_bytes(geom: ConvGeom, plan: KernelPlan) -> int:
         ah, aw = mh + kt - 1, mw + ktw - 1
         nth = -(-(th + 1) // mh)
         ntw = -(-(tw + 1) // mw)
-        band = (nth * mh + kt - 1) * (ntw * mw + ktw - 1) * plan.tcin
-        filt = ah * aw * plan.tcin * plan.tcout * phases
-        acc = ah * aw * nth * ntw * plan.tcout * phases
-        vtmp = ah * aw * nth * ntw * plan.tcin
-        out = th * s * tw * sw * plan.tcout
-        return isz * (band + filt) + 4 * (acc + vtmp + out)
-    band = (th + 1 + kt - 1) * (tw + 1 + ktw - 1) * plan.tcin
-    filt = kt * ktw * plan.tcin * plan.tcout * phases
-    acc = (th + 1) * (tw + 1) * plan.tcout * phases
-    out = th * s * tw * sw * plan.tcout
-    # Chained launches write an int8 output tile: 1 byte per element
-    # (the accumulator stays int32/f32 — requant happens at the write).
+        lanes_in = _round_up(plan.tcin, 128)
+        lanes_ph = _round_up(plan.tcout * phases, 128)
+        band = ((nth * mh + kt - 1) * _round_up(ntw * mw + ktw - 1, 8)
+                * lanes_in)
+        filt = ah * aw * _round_up(plan.tcin, 8) * lanes_ph
+        acc = ah * aw * nth * ntw * lanes_ph
+        vtmp = ah * aw * nth * ntw * lanes_in
+        out = (th * s * _round_up(tw * sw, 8)
+               * _round_up(plan.tcout, 128))
+        return 2 * (isz * (band + filt) + 4 * out) + 4 * (acc + vtmp)
+    # Mirrors sd_conv.sd_fused_pallas: conv columns (residual included)
+    # round up to the operand's sublane tile, the band to 8 rows, and
+    # every VMEM buffer pads its channels to 128 lanes.
+    tile = 32 // isz
+    cols = _round_up(tw + 1, tile)
+    lanes_in, lanes_out = _round_up(plan.tcin, 128), _round_up(plan.tcout,
+                                                               128)
+    band = (th + kt) * _round_up(cols + ktw - 1, tile) * lanes_in * isz
+    filt = kt * ktw * phases * _round_up(plan.tcin, tile) * lanes_out * isz
+    acc = phases * (th + 1) * cols * lanes_out * 4
+    ytile = th * s * _round_up(tw * sw, 8) * lanes_out * 4
+    # The epilogue interleaves into the f32 tile, then writes the output
+    # block: 1 byte per element for chained int8 launches, else 4.
     osz = 1 if geom.qout else 4
-    return isz * (band + filt) + 4 * acc + osz * out
+    out = th * s * _round_up(tw * sw, 32 // osz) * lanes_out * osz
+    return 2 * (band + filt + out) + acc + ytile
 
 
 def _fits_budget(geom: ConvGeom, plan: KernelPlan) -> bool:
-    kt, ktw = geom.kt, geom.ktw or geom.kt
-    if geom.algo == "wino":             # transformed taps: alpha per dim
-        kt, ktw = (kt + (0 if kt == 1 else 1),
-                   ktw + (0 if ktw == 1 else 1))
-    phases = geom.s * (geom.sw or geom.s)
-    return (vmem_plan_bytes(geom, plan) <= VMEM_BUDGET
-            and kt * ktw * plan.tcin * plan.tcout * phases
-            * geom.operand_itemsize <= _FILTER_BUDGET)
+    return vmem_plan_bytes(geom, plan) <= VMEM_BUDGET
+
+
+def smaller_lane_tile(c: int, t: int) -> Optional[int]:
+    """The largest of :func:`lane_tiles` below ``t``, or None."""
+    smaller = [u for u in lane_tiles(c) if u < t]
+    return max(smaller) if smaller else None
+
+
+def _smaller_col_tile(tw: int, ow: int) -> Optional[int]:
+    """Next width band below ``tw`` (0 = full width): a multiple of 128,
+    so both the band and the output block keep their sublane tiling."""
+    t = (tw or ow) // 2 // 128 * 128
+    return t if t >= 128 else None
 
 
 def heuristic_plan(geom: ConvGeom) -> KernelPlan:
@@ -332,7 +352,7 @@ def heuristic_plan(geom: ConvGeom) -> KernelPlan:
     rule pads OH=34 by 41%; a divisor-only rule collapses to th=1 on
     prime OH — both pathological).  Width: full bands until the VMEM
     model says otherwise.  Channels: full depth unless the budget forces
-    tiling of the deeper axis."""
+    tiling of the deeper axis, in 128-lane steps (:func:`lane_tiles`)."""
     th = _pick_th(geom)
     tcin, tcout, tw = geom.cin, geom.cout, 0
     phases = geom.s * (geom.sw or geom.s)
@@ -341,12 +361,15 @@ def heuristic_plan(geom: ConvGeom) -> KernelPlan:
         # Shrink the axis that buys the most: channels first (they scale
         # both the filter block and the accumulator), then the band
         # width, then the row band.
-        if tcin >= tcout * phases and tcin % 2 == 0:
-            tcin //= 2
-        elif tcout % 2 == 0:
-            tcout //= 2
-        elif (tw or geom.ow) > 8:
-            tw = max(8, (tw or geom.ow) // 2)
+        cin_t = smaller_lane_tile(geom.cin, tcin)
+        cout_t = smaller_lane_tile(geom.cout, tcout)
+        tw_t = _smaller_col_tile(tw, geom.ow)
+        if cin_t and (tcin >= tcout * phases or not cout_t):
+            tcin = cin_t
+        elif cout_t:
+            tcout = cout_t
+        elif tw_t:
+            tw = tw_t
         elif th > 1:
             th = max(1, th // 2)
         else:
@@ -355,14 +378,9 @@ def heuristic_plan(geom: ConvGeom) -> KernelPlan:
 
 
 def _col_tile_options(geom: ConvGeom) -> List[int]:
-    """Width-band candidates: full width (0) plus halved bands down to
-    the 128-lane granularity — only worth searching on wide layers."""
-    opts = [0]
-    tw = geom.ow
-    while tw > 128:
-        tw = -(-tw // 2)
-        opts.append(tw)
-    return opts
+    """Width-band candidates: full width (0) plus 128-multiple bands
+    narrower than the output — only worth searching on wide layers."""
+    return [0] + [t for t in (128, 256, 512, 1024) if t < geom.ow]
 
 
 def candidate_plans(geom: ConvGeom, max_candidates: int = 8,
@@ -389,8 +407,8 @@ def candidate_plans(geom: ConvGeom, max_candidates: int = 8,
     seen = {base}
     for th in sorted(ths, reverse=True):
         for tw in sorted(tws):
-            for tcin in _divisor_tiles(geom.cin):
-                for tcout in _divisor_tiles(geom.cout):
+            for tcin in lane_tiles(geom.cin):
+                for tcout in lane_tiles(geom.cout):
                     p = KernelPlan(th=th, tcin=tcin, tcout=tcout, tw=tw)
                     if p in seen:
                         continue
@@ -500,7 +518,8 @@ def tune(geom: ConvGeom,
     """Benchmark ``runner(plan) -> ms`` over the candidate set, persist
     and return the winner.  A cached measured plan short-circuits unless
     ``force``.  Candidates that raise are skipped (e.g. a tile shape the
-    backend rejects).
+    backend rejects); when every candidate raises, the first error is
+    raised — a compile refusal never turns into a silent fallback.
 
     ``cost_fn`` (optional) breaks wall-clock near-ties: among plans
     within ``tie_rtol`` of the fastest, the one with the lowest cost
@@ -523,15 +542,19 @@ def tune(geom: ConvGeom,
     # the candidate list in opposite directions instead of crowning
     # whichever candidate ran at the quiet moment.
     best: Dict[KernelPlan, float] = {}
+    first_error: Optional[Exception] = None
     for plans_pass in (valid, valid[::-1]):
         for plan in plans_pass:
             try:
                 ms = runner(plan)
-            except Exception:
+            except Exception as e:
+                first_error = first_error or e
                 continue
             best[plan] = min(ms, best.get(plan, float("inf")))
-    if not best:                # every candidate failed: keep heuristic
-        return heuristic_plan(geom)
+    if not best:
+        if first_error is None:
+            raise ValueError(f"no candidate tile divides {geom.key()}")
+        raise first_error
     best_plan, best_ms = min(best.items(), key=lambda kv: kv[1])
     if cost_fn is not None:
         near = [p for p, ms in best.items()

@@ -1,15 +1,16 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Since the zero-copy rework the fused deconv path touches HBM exactly
-once per tensor: the ``P_I`` input pad is applied *inside* the kernel
-(border-masked halo reads), the ``P_K`` + user-padding crop is folded
-into the epilogue (offset band + trimmed ``out_shape``), and row/col
-grids ceil-divide the output so no alignment padding exists either.
-The old pad -> kernel -> crop composition survives as
-``zero_copy=False`` — it is the reference the parity tests and the CI
-HBM-traffic gate compare against.
+The fused deconv path writes each output once, at final geometry: the
+``P_K`` + user-padding crop is folded into the epilogue (offset band +
+phase-offset stores + trimmed ``out_shape``), and row/col grids
+ceil-divide the output so no alignment padding exists.  The ``P_I``
+input pad is one zero pad per launch (Mosaic refuses element windows
+below the array).  The old pad -> kernel -> crop composition survives
+as ``zero_copy=False`` — it is the reference the parity tests and the
+CI HBM-traffic gate compare against.
 
-These wrappers select interpret mode automatically off-TPU and consult
+These wrappers run the kernels compiled on TPU and in Pallas interpret
+mode on any other backend (the CPU test suite), and consult
 the autotuner (:mod:`repro.kernels.autotune`) for ``(th, tw, tcin,
 tcout)`` tile plans when the caller does not pin one — the hardcoded
 row-tile heuristic of the seed lives on only as the autotuner's
@@ -91,8 +92,8 @@ def sd_conv2d_valid(x: jax.Array, w: jax.Array, th: int | None = None,
                     ) -> jax.Array:
     """Stride-1 conv (B,H,W,Cin)x(KTh,KTw,Cin,Co) via the Pallas kernel.
 
-    ``pad`` is zero padding applied *in kernel* (border-masked reads, no
-    padded HBM copy); ``out_start``/``out_size`` select a contiguous
+    ``pad`` is zero padding (one padded copy per launch);
+    ``out_start``/``out_size`` select a contiguous
     output window so downstream crops fold into the launch.  The plan
     lookup happens OUTSIDE jit so the jit cache is keyed on the resolved
     tiles — plans tuned later in the process take effect on the next
@@ -177,12 +178,11 @@ def sd_deconv_presplit_fused(x: jax.Array, ws_ocmajor: jax.Array,
     """2-D transposed conv from *pre-split* oc-major filters via the
     fused Pallas kernel.
 
-    The zero-copy default touches HBM exactly once per tensor: the
-    ``P_I`` pad is border-masked halo reads, the ``P_K`` + user-padding
-    crop is the phase-offset epilogue writing final output geometry, and
-    ``output_padding`` rows past the shuffled support come out of the
-    kernel as ``act(bias)`` (their input windows are fully masked) — no
-    out-of-kernel extend fallback.  ``zero_copy=False`` is the old
+    The zero-copy default writes the output once: the ``P_K`` +
+    user-padding crop is the phase-offset epilogue writing final output
+    geometry, and ``output_padding`` rows past the shuffled support come
+    out of the kernel as ``act(bias)`` (their input windows are zero
+    padding) — no out-of-kernel extend fallback.  ``zero_copy=False`` is the old
     pad -> kernel -> crop composition, kept as the parity/traffic
     reference.
 
@@ -293,9 +293,9 @@ def sd_deconv_presplit_wino(x: jax.Array, u: jax.Array,
     ``u`` is the oc-major split filter stack already passed through the
     F(2,r) filter transform (``plan.bind`` on a winograd plan, or
     :func:`repro.kernels.winograd.transform_filters`): shape
-    ``(alpha_h, alpha_w, Cin, Cout*prod(s))``.  Same zero-copy contract
-    as :func:`sd_deconv_presplit_fused` — the ``P_I`` pad is masked halo
-    reads, the ``P_K`` + user crop and the inverse output transform are
+    ``(alpha_h, alpha_w, Cin, Cout*prod(s))``.  Same contract as
+    :func:`sd_deconv_presplit_fused` — the ``P_I`` pad is the launch's
+    zero pad, the ``P_K`` + user crop and the inverse output transform are
     folded into the epilogue together with bias/act/interleave.  Float
     only (no int8 path); the autotune plan cache keys these launches
     under ``algo="wino"`` so direct and Winograd tiles never collide.
@@ -369,8 +369,8 @@ def sd_deconv_presplit_fused_1d(x: jax.Array, ws_ocmajor: jax.Array,
     x: (B, L, Cin); ws_ocmajor: (KT, Cin, Cout*s) with channel
     c = oc*s + phase.  The length axis becomes the kernel's width axis
     (a (1, KT) filter, interleave (1, s)) — same kernel, no wasted MACs,
-    and the zero-copy pad/crop folding applies to the length axis via
-    the kernel's width machinery.  ``scale`` (int8): (B, Cout*s),
+    and the pad/crop folding applies to the length axis via the
+    kernel's width machinery.  ``scale`` (int8): (B, Cout*s),
     oc-major — the (1, s) lowering keeps the phase-channel order.
     """
     (k,) = _ntuple(kernel, 1)
@@ -400,7 +400,7 @@ def sd_deconv_presplit_fused_3d(x: jax.Array, ws_nmajor: jax.Array,
     stride-1 conv is an *intra-slice* 2-D conv applied to a shifted band
     of depth slices — so each tap runs through the 2-D Pallas conv
     kernel with (B * D_out) as the batch axis and the H/W ``P_I`` pads
-    applied *in kernel* (only the depth pad is materialised, to slice
+    applied by its launch (the depth pad is materialised here, to slice
     the tap bands from); the cross-slice coupling is a plain f32
     accumulation over the KT_d taps, and the 3-D interleave + bias/act
     epilogue falls back to grouped-XLA layout ops (``depth_to_space``).
@@ -492,9 +492,9 @@ def sd_input_grad_fused(dy1: jax.Array, ws: jax.Array,
                         plan: KernelPlan | None = None) -> jax.Array:
     """VJP of ``y1 = conv_valid_stride1(pad(x, P_I), ws)`` w.r.t. ``x``,
     on the Pallas kernel: a FULL stride-1 conv of ``dy1`` with the
-    rot180, channel-swapped split filters, expressed as a pad-masked
-    VALID conv — the ``(K_T - 1)`` FULL-conv pad is border-masked halo
-    reads, and the trailing ``P_I`` crop (the pad^T of the forward) is
+    rot180, channel-swapped split filters, expressed as a padded
+    VALID conv — the ``(K_T - 1)`` FULL-conv pad is the launch's zero
+    pad, and the trailing ``P_I`` crop (the pad^T of the forward) is
     folded into the launch as an output window, so ``dx`` is written
     directly at final geometry.
 
@@ -514,24 +514,35 @@ def sd_input_grad_fused(dy1: jax.Array, ws: jax.Array,
         tuple(space))
 
 
-def _dw_fit_channels(o1: int, tcin: int, tcout: int) -> Tuple[int, int]:
-    """Shrink channel tiles until the filter-grad kernel's *actual*
-    per-step footprint fits VMEM.  Its blocks span the full ``O1``
-    extent (x: ``o1*tcin``, dy1: ``o1*tcout``, plus the accumulator and
-    output tile) — the generic conv-band model the autotuner's
-    heuristic uses does not describe this kernel, and full channel
-    depth on a wide layer would blow VMEM on TPU."""
-    def nbytes(ci: int, co: int) -> int:
-        return 4 * (o1 * ci + o1 * co + 2 * ci * co)
+def _dw_tiles(o1: Tuple[int, int], wp: int, kt: Tuple[int, int],
+              cin: int, nco: int) -> Tuple[int, int, int]:
+    """``(tr, tcin, tcout)`` of the filter-grad kernel: full channel
+    depth and all ``O1h`` rows, shrunk until its *actual* per-step
+    footprint fits VMEM — a band of ``tr + KTh - 1`` padded input rows
+    at the full padded width ``wp``, ``tr`` rows of ``dy1``, the per-tap
+    accumulator and the output tile.  The generic conv-band model the
+    autotuner's heuristic uses does not describe this kernel.  Channels
+    shrink first, in 128-lane steps, then the row band."""
+    (o1h, o1w), (kth, ktw) = o1, kt
+    wp8 = -(-wp // 8) * 8
 
-    while nbytes(tcin, tcout) > autotune.VMEM_BUDGET:
-        if tcin >= tcout and tcin % 2 == 0:
-            tcin //= 2
-        elif tcout % 2 == 0:
-            tcout //= 2
+    def nbytes(tr: int, ci: int, co: int) -> int:
+        return 4 * ((tr + kth - 1) * wp8 * ci + tr * o1w * co
+                    + 2 * kth * ktw * ci * co)
+
+    tr, tcin, tcout = o1h, cin, nco
+    while nbytes(tr, tcin, tcout) > autotune.VMEM_BUDGET:
+        ci = autotune.smaller_lane_tile(cin, tcin)
+        co = autotune.smaller_lane_tile(nco, tcout)
+        if ci and (tcin >= tcout or not co):
+            tcin = ci
+        elif co:
+            tcout = co
+        elif tr > 1:
+            tr = -(-tr // 2)
         else:
             break
-    return tcin, tcout
+    return tr, tcin, tcout
 
 
 def sd_filter_grad_fused(x: jax.Array, dy1: jax.Array,
@@ -539,33 +550,28 @@ def sd_filter_grad_fused(x: jax.Array, dy1: jax.Array,
                          plan: KernelPlan | None = None) -> jax.Array:
     """VJP of ``y1 = conv_valid_stride1(pad(x, P_I), ws)`` w.r.t. ``ws``
     on the Pallas filter-grad kernel: the batch/channel-exchanged VALID
-    conv, with the ``P_I`` activation pad applied in kernel — the padded
-    activation copy of the XLA formulation never exists.
+    conv, with the ``P_I`` activation pad applied by the launch.
 
     x: (B, H, W, Cin) *unpadded*; dy1: (B, O1h, O1w, N*Co);
-    returns dws: (KTh, KTw, Cin, N*Co).  Unpinned channel tiles are
-    clamped to this kernel's own VMEM footprint (see _dw_fit_channels);
-    an explicitly pinned ``plan`` is trusted as-is.
+    returns dws: (KTh, KTw, Cin, N*Co).  Unpinned tiles come from this
+    kernel's own VMEM footprint (see _dw_tiles); an explicitly pinned
+    ``plan``'s channel tiles are trusted as-is.
     """
-    b, h, wd, cin = x.shape
+    _, h, wd, cin = x.shape
     _, o1h, o1w, nco = dy1.shape
-    kth, ktw = kt
     if plan is not None:
-        tcin, tcout = plan.tcin, plan.tcout
+        tr, tcin, tcout = o1h, plan.tcin, plan.tcout
     else:
-        geom = ConvGeom(b, h + 2 * pi[0], wd + 2 * pi[1], cin, nco, kth,
-                        1, ktw=0 if ktw == kth else ktw, tag="dw")
-        rplan = autotune.get_plan(geom)
-        tcin, tcout = _dw_fit_channels(o1h * o1w, rplan.tcin,
-                                       rplan.tcout)
+        tr, tcin, tcout = _dw_tiles((o1h, o1w), wd + 2 * pi[1], kt, cin,
+                                    nco)
     return _sd_filter_grad_jit(x, dy1, kt, tuple((p, p) for p in pi),
-                               tcin, tcout)
+                               tr, tcin, tcout)
 
 
-@functools.partial(jax.jit, static_argnames=("kt", "pad", "tcin",
+@functools.partial(jax.jit, static_argnames=("kt", "pad", "tr", "tcin",
                                              "tcout"))
-def _sd_filter_grad_jit(x: jax.Array, dy1: jax.Array, kt, pad,
+def _sd_filter_grad_jit(x: jax.Array, dy1: jax.Array, kt, pad, tr: int,
                         tcin: int, tcout: int) -> jax.Array:
-    return _k.sd_filter_grad_pallas(x, dy1, kt, pad=pad, tcin=tcin,
+    return _k.sd_filter_grad_pallas(x, dy1, kt, pad=pad, tr=tr, tcin=tcin,
                                     tcout=tcout,
                                     interpret=not _on_tpu())

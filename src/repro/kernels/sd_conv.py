@@ -1,59 +1,52 @@
-"""Pallas TPU kernels for Split Deconvolution — zero-copy edition.
+"""Pallas TPU kernels for Split Deconvolution.
 
 Three kernels:
 
 * ``sd_conv_kernel``        — stride-1 VALID convolution with the stacked
   split filters (the grouped-GEMM view of SD).  Generic small-K conv
-  kernel, now with *in-kernel zero padding* (border-masked halo reads)
-  and an optional contiguous output window, so FULL convs and cropped
-  outputs never materialise padded/uncropped copies in HBM.
+  kernel with zero padding and an optional contiguous output window, so
+  FULL convs and cropped outputs never materialise uncropped copies in
+  HBM.
 * ``sd_fused_kernel``       — the same convolution, but each block *also*
-  performs the paper's stride-``s`` output write: the s^2 phase outputs
-  are interleaved into the deconv output tile inside VMEM, the bias +
-  activation epilogue runs on the interleaved tile, and the ``P_K`` +
-  user-padding crop is folded into the write (phase-offset epilogue +
-  trimmed ``out_shape``) — the tile leaves VMEM in final output
-  geometry.
+  performs the paper's stride-``s`` output write: every phase
+  accumulates on its own, the bias + activation epilogue runs per phase,
+  and strided VMEM stores interleave the phases into the deconv output
+  tile.  The ``P_K`` + user-padding crop is folded into the write
+  (phase-offset stores + trimmed ``out_shape``) — the tile leaves VMEM
+  in final output geometry.
 * ``sd_filter_grad_kernel`` — the filter-gradient VALID conv of the SD
-  backward (``dw[t] = sum_{b,v} xpad[b, v+t] dy1[b, v]``): one MXU
-  GEMM per (tap, cin-tile, cout-tile) grid step, batch as the innermost
-  accumulation axis.  Taps are the *output* spatial dim here, so the
-  generic conv kernel (which unrolls taps) cannot express it.
+  backward (``dw[t] = sum_{b,v} xpad[b, v+t] dy1[b, v]``): one MXU GEMM
+  per tap over a band of ``dy1`` rows, with batch and row bands as the
+  accumulation axes.
 
-Zero-copy TPU mapping (see DESIGN.md "Memory traffic"):
-  - inputs are bound with ``pl.Unblocked(padding=...)`` element windows:
-    the index map may reach up to ``P_I`` (+ grid alignment) elements
-    outside the array and the kernel zero-fills the out-of-range
-    rows/cols of the VMEM band (``lax.broadcasted_iota`` masks) instead
-    of reading a padded HBM copy.  Off TPU (interpret mode) Pallas
-    materialises that window with *uninitialised* values, so the masks
-    are mandatory for correctness everywhere.
+TPU mapping (see DESIGN.md "Memory traffic"):
+  - the ``P_I`` halo is a zero pad applied by the wrapper (``_window``,
+    one XLA pad per launch).  Mosaic accepts element windows only inside
+    the array, so the bands read no out-of-range rows and the kernels
+    need no border masks.  Input bands are bound with element-indexed
+    block dims (``pl.Element``): the index map returns element offsets,
+    which is what lets consecutive bands overlap by the (K_T - 1) halo.
+  - the conv columns a step computes round up to the operand's sublane
+    tile (8 rows of f32), the band width to a multiple of 8; the extra
+    columns are zero padding and feed no written output.
   - grid = (batch, out-row-tiles, out-col-tiles, cout-tiles, cin-tiles)
     with the input-channel (reduction) axis innermost and marked
     ``arbitrary``; the four outer axes are ``parallel``.  Row/col grids
     ceil-divide the output — trailing partial blocks are Pallas-managed.
   - each step loads an input band with a (K_T - 1) halo per spatial dim
-    and a (K_Th, K_Tw, TCin, TCout) filter block, and issues K_Th*K_Tw
-    MXU matmuls of shape (rows*cols, TCin) x (TCin, TCout).
-  - partial sums live in an f32 VMEM scratch accumulator that persists
-    across the Cin-tile grid steps; the output block is written exactly
-    once, by the epilogue at the last Cin tile (no HBM read-modify-write).
-  - inputs may be bf16; the MXU accumulates in f32 and the epilogue casts
-    back to the output dtype.
-
-Crop folding (the fused kernel).  With total low-side crop ``c`` per
-dim (``P_K`` + user padding), write ``c = s*q + r``: dropping ``q``
-whole interleave rows shifts the input band by ``q`` conv rows, and the
-residual ``r`` is a static slice of the interleaved VMEM tile — each
-grid step computes ``th + (1 if r else 0)`` conv rows, interleaves
-them, slices ``[r : r + th*s)`` and writes straight into final output
-geometry.  ``output_padding`` rows past the shuffled support fall out
-naturally: their input windows are fully masked, so the kernel writes
-``act(0 + bias)`` — exactly the zero-extension + epilogue semantics of
-the old out-of-kernel fallback.
+    and issues K_Th*K_Tw MXU matmuls (times ``s^2`` phases in the fused
+    kernel) of shape (rows*cols, TCin) x (TCin, TCout).
+  - partial sums live in a VMEM scratch accumulator that persists across
+    the Cin-tile grid steps; the output block is written exactly once, by
+    the epilogue at the last Cin tile.
+  - channel tiles are the full depth or a multiple of 128 lanes (see
+    ``autotune.lane_tiles``); the interleave uses strided stores, which
+    Mosaic lowers for 32-bit data only, so the fused epilogue assembles
+    the tile in an f32 scratch and casts it once on the way out.
 
 Validated in interpret mode against ``ref.py`` (tests/test_kernels.py,
-tests/test_zero_copy.py).
+tests/test_zero_copy.py) and compiled for TPU v5e by
+tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -66,15 +59,11 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 PadPair = Tuple[int, int]
 
 
 def _compiler_params(n_parallel: int, n_arbitrary: int):
-    return _CompilerParams(dimension_semantics=(
+    return pltpu.CompilerParams(dimension_semantics=(
         ("parallel",) * n_parallel + ("arbitrary",) * n_arbitrary))
 
 
@@ -92,76 +81,119 @@ def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
-def _mask_band(xb: jax.Array, row0, col0, *, h: int, w: int,
-               pad_h: PadPair, pad_w: PadPair,
-               mask_h: bool, mask_w: bool) -> jax.Array:
-    """Zero-fill the out-of-range rows/cols of one VMEM input band.
+def _round8(n: int) -> int:
+    return _cdiv(n, 8) * 8
 
-    ``xb``: (band_h, band_w, tc); ``row0``/``col0``: padded-coordinate
-    offset of element [0, 0] (traced).  Real data occupies padded rows
-    ``[pad_lo, pad_lo + extent)`` per dim; everything else in the
-    element window is uninitialised (interpret mode) or garbage (TPU
-    element window) and must read as the logical zero padding.  The
-    masks are elided entirely (``mask_* == False``) when the launch has
-    no padding on that dim — pre-padded callers pay nothing.
-    """
-    bh, bw = xb.shape[0], xb.shape[1]
-    mask = None
-    if mask_h:
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bh, bw), 0) + row0
-        mask = (rows >= pad_h[0]) & (rows < pad_h[0] + h)
-    if mask_w:
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bh, bw), 1) + col0
-        mw = (cols >= pad_w[0]) & (cols < pad_w[0] + w)
-        mask = mw if mask is None else (mask & mw)
-    if mask is None:
-        return xb
-    return jnp.where(mask[..., None], xb, jnp.zeros((), xb.dtype))
+
+def _round_sublanes(n: int, dtype) -> int:
+    """``n`` rounded up to the sublane tile of ``dtype``: 8 rows of
+    32-bit data, 16 of bf16, 32 of int8.  Band columns computed at that
+    width keep every (rows, cols, C) <-> (rows*cols, C) reshape a pure
+    retiling."""
+    tile = 8 * 4 // jnp.dtype(dtype).itemsize
+    return _cdiv(n, tile) * tile
+
+
+# Pixels one inner-loop step processes.  Mosaic unrolls a kernel body's
+# vector ops over their (8, 128) tiles, so a body that works on a whole
+# (rows x cols) tile at once compiles in minutes at MDE's widths; the
+# row-chunk loops keep the unrolled code, and the compile time, bounded.
+_CHUNK_PIXELS = 512
+
+
+def _row_chunk(rows: int, cols: int) -> int:
+    """Rows per loop step: the largest divisor of ``rows`` whose
+    (chunk x cols) slab stays within ``_CHUNK_PIXELS`` (at least 1)."""
+    return max(r for r in range(1, rows + 1)
+               if rows % r == 0 and (r == 1 or r * cols <= _CHUNK_PIXELS))
+
+
+def _for_row_chunks(rows: int, cols: int, step) -> None:
+    """Run ``step(r, n)`` over row chunks ``[r, r + n)`` of ``rows``."""
+    n = _row_chunk(rows, cols)
+
+    def body(c, carry):
+        step(c * n, n)
+        return carry
+
+    jax.lax.fori_loop(0, rows // n, body, 0)
+
+
+def _window(x: jax.Array, lo: Tuple[int, int],
+            size: Tuple[int, int]) -> jax.Array:
+    """Rows ``[-lo[0], size[0] - lo[0])`` x cols ``[-lo[1], ...)`` of
+    ``x`` (B, H, W, C), zero outside ``x``.  A negative ``lo`` (or a
+    negative implied high side) crops instead of padding."""
+    _, h, w, _ = x.shape
+    cfg = ((0, 0, 0), (lo[0], size[0] - lo[0] - h, 0),
+           (lo[1], size[1] - lo[1] - w, 0), (0, 0, 0))
+    return jax.lax.pad(x, jnp.zeros((), x.dtype), cfg)
+
+
+def _band_spec(band_h: int, band_w: int, tcin: int, row: int, col: int,
+               nw: int, nci: int):
+    """Element-indexed (1, band_h, band_w, tcin) input band of grid step
+    (bi, i, j, co, ci): rows from ``i*row``, cols from ``j*col``.  A
+    tiled (col, channel) dim with one grid step gets the literal offset
+    0: Mosaic must prove the offset divides by the (8, 128) tiling."""
+    def index(bi, i, j, co, ci):
+        return (bi, i * row, j * col if nw > 1 else 0,
+                ci * tcin if nci > 1 else 0)
+    return pl.BlockSpec(
+        (pl.Element(1), pl.Element(band_h), pl.Element(band_w),
+         pl.Element(tcin)), index)
 
 
 def _is_int8_pair(x: jax.Array, w: jax.Array) -> bool:
     return x.dtype == jnp.int8 and w.dtype == jnp.int8
 
 
-def _conv_partial(x, w, *, kth: int, ktw: int, rows: int,
-                  cols: int) -> jax.Array:
-    """K_T_h*K_T_w MXU matmuls over one (band, cin-tile, cout-tile) block.
+def _dot(a: jax.Array, b: jax.Array, contract: int = 1) -> jax.Array:
+    """One MXU matmul contracting ``a``'s dim ``contract`` with ``b``'s
+    dim 0.  Int8 pairs run the MXU's 8-bit mode into exact int32; bf16
+    pairs run natively into f32 (bf16 products are exact in f32);
+    anything else runs in f32 at HIGHEST precision — the MXU's default
+    f32 path rounds operands to bf16."""
+    dims = (((contract,), (0,)), ((), ()))
+    if _is_int8_pair(a, b):
+        return jax.lax.dot_general(a, b, dims,
+                                   preferred_element_type=jnp.int32)
+    if a.dtype == b.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(a, b, dims,
+                                   preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(a.astype(jnp.float32), b.astype(jnp.float32),
+                               dims, precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
-    x: (rows+KTh-1, cols+KTw-1, TCin); w: (KTh, KTw, TCin, TC).
-    Returns the partial sum of shape (rows*cols, TC): f32 for float
-    operands; for an int8 (x, w) pair the dot runs int8-in with
-    ``preferred_element_type=int32`` — the MXU's native 8-bit mode, no
-    operand casts — and the partial sum is exact int32.
-    """
-    tcin = x.shape[-1]
-    if _is_int8_pair(x, w):
-        acc = jnp.zeros((rows * cols, w.shape[-1]), jnp.int32)
+
+def _accumulate(x_ref, w_ref, acc_ref, *, kth: int, ktw: int, rows: int,
+                cols: int) -> None:
+    """``acc_ref[p, r] += sum_taps x[r + kh, kw : kw + cols] @ w[kh, kw, p]``
+    for conv rows ``r < rows`` and every phase ``p``.
+
+    x_ref: (1, band_h, band_w, TCin) band; w_ref: (KTh, KTw, P, TCin,
+    TCout); acc_ref: (P, rows, cols, TCout).  Each tap's im2col slab is
+    loaded once per row chunk and feeds one MXU matmul per phase."""
+    tcin = x_ref.shape[-1]
+
+    def step(r, n):
         for kh in range(kth):
             for kw in range(ktw):
-                patch = x[kh:kh + rows, kw:kw + cols, :].reshape(
-                    rows * cols, tcin)
-                acc += jnp.dot(patch, w[kh, kw],
-                               preferred_element_type=jnp.int32)
-        return acc
-    acc = jnp.zeros((rows * cols, w.shape[-1]), jnp.float32)
-    for kh in range(kth):
-        for kw in range(ktw):
-            patch = x[kh:kh + rows, kw:kw + cols, :].reshape(
-                rows * cols, tcin)
-            acc += jnp.dot(patch.astype(jnp.float32),
-                           w[kh, kw].astype(jnp.float32),
-                           preferred_element_type=jnp.float32)
-    return acc
+                patch = x_ref[0, pl.ds(r + kh, n), kw:kw + cols, :]
+                patch = patch.reshape(n * cols, tcin)
+                for p in range(acc_ref.shape[0]):
+                    acc_ref[p, pl.ds(r, n)] += _dot(
+                        patch, w_ref[kh, kw, p]).reshape(n, cols, -1)
+
+    _for_row_chunks(rows, cols, step)
 
 
 # ---------------------------------------------------------------------------
-# Generic stride-1 conv kernel (in-kernel pad + output window)
+# Generic stride-1 conv kernel (pad + output window)
 # ---------------------------------------------------------------------------
 
 def _sd_conv_body(x_ref, w_ref, o_ref, acc_ref, *, kth: int, ktw: int,
-                  th: int, tw: int, h: int, w: int, osh: int, osw: int,
-                  pad_h: PadPair, pad_w: PadPair,
-                  mask_h: bool, mask_w: bool):
+                  th: int, tw: int, cols: int):
     """One (batch, row-tile, col-tile, cout-tile, cin-tile) grid step."""
     ci = pl.program_id(4)
 
@@ -169,18 +201,15 @@ def _sd_conv_body(x_ref, w_ref, o_ref, acc_ref, *, kth: int, ktw: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    xb = x_ref[0]
-    if mask_h or mask_w:
-        row0 = pl.program_id(1) * th + osh
-        col0 = pl.program_id(2) * tw + osw
-        xb = _mask_band(xb, row0, col0, h=h, w=w, pad_h=pad_h,
-                        pad_w=pad_w, mask_h=mask_h, mask_w=mask_w)
-    acc_ref[...] += _conv_partial(xb, w_ref[...], kth=kth, ktw=ktw,
-                                  rows=th, cols=tw)
+    _accumulate(x_ref, w_ref, acc_ref, kth=kth, ktw=ktw, rows=th, cols=cols)
 
     @pl.when(ci == pl.num_programs(4) - 1)
     def _write():
-        o_ref[0] = acc_ref[...].reshape(th, tw, -1).astype(o_ref.dtype)
+        def step(r, n):
+            o_ref[0, pl.ds(r, n)] = acc_ref[0, pl.ds(r, n), :tw].astype(
+                o_ref.dtype)
+
+        _for_row_chunks(th, cols, step)
 
 
 def sd_conv_pallas(x: jax.Array, w: jax.Array, *, th: int = 8,
@@ -189,24 +218,22 @@ def sd_conv_pallas(x: jax.Array, w: jax.Array, *, th: int = 8,
                    pad: Tuple[PadPair, PadPair] = ((0, 0), (0, 0)),
                    out_start: Tuple[int, int] = (0, 0),
                    out_size: Optional[Tuple[int, int]] = None,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool) -> jax.Array:
     """Stride-1 VALID conv over the logically zero-padded input.
 
     x: (B, H, W, Cin); w: (KTh, KTw, Cin, Co) — rectangular filters
     allowed (the 1-D rank lowering runs a (1, KT) filter).  An int8
     (x, w) pair accumulates in int32 and returns the exact int32 conv
-    (symmetric quantization: the in-kernel zero padding is the int8
-    zero, so the masked halo stays correct); the caller owns the
-    dequant.
+    (symmetric quantization: the zero padding is the int8 zero); the
+    caller owns the dequant.
 
-    ``pad`` is applied *in kernel*: the launch binds ``x`` with an
-    ``Unblocked`` element window and zero-masks the out-of-range band
-    rows/cols in VMEM — no padded HBM copy exists.  ``out_start`` /
-    ``out_size`` select a contiguous window of the conv output (in conv
-    output == padded-input coordinates), folding any downstream crop
-    into the launch.  ``tw == 0`` means no width tiling (one band spans
-    the full output width).  Row/col grids ceil-divide the output; the
-    trailing partial blocks are handled by Pallas.
+    ``pad`` is the logical zero padding; ``out_start`` / ``out_size``
+    select a contiguous window of the conv output (in conv output ==
+    padded-input coordinates), folding any downstream crop into the
+    launch.  The wrapper pads (and crops) ``x`` once to exactly the rows
+    and cols the grid reads.  ``tw == 0`` means no width tiling (one
+    band spans the full output width).  Row/col grids ceil-divide the
+    output; the trailing partial blocks are handled by Pallas.
 
     Output: (B, out_size[0], out_size[1], Co); defaults to the full conv
     output ``(H + pad - KT + 1)`` per dim.
@@ -218,135 +245,113 @@ def sd_conv_pallas(x: jax.Array, w: jax.Array, *, th: int = 8,
     full_ow = wd + plo_w + phi_w - ktw + 1
     osh, osw = out_start
     oh, ow = out_size if out_size is not None else (full_oh, full_ow)
-    tw = tw or ow
     th = min(th, oh)
-    tw = min(tw, ow)
+    tw = min(tw or ow, ow)
     tcout = tcout or cout
     tcin = tcin or cin
     assert cout % tcout == 0 and cin % tcin == 0
 
-    # Origin shift: reads start at padded coordinate ``out_start`` — the
-    # first min(out_start, pad_lo) padded rows/cols are never touched,
-    # so don't put them in the element window (off TPU that also keeps
-    # the window aligned to the band, avoiding the interpreter's
-    # round-up-to-block copies).
-    sh_h, sh_w = min(osh, plo_h), min(osw, plo_w)
-    plo_h, osh = plo_h - sh_h, osh - sh_h
-    plo_w, osw = plo_w - sh_w, osw - sh_w
-
     nh, nw = _cdiv(oh, th), _cdiv(ow, tw)
-    # Element-window extents: the grid's ceil-division may over-reach the
-    # logical padding on the high side; grow the window (masked anyway).
-    win_hi_h = max(0, (nh - 1) * th + osh + th + kth - 1 - (plo_h + h))
-    win_hi_w = max(0, (nw - 1) * tw + osw + tw + ktw - 1 - (plo_w + wd))
-    mask_h = plo_h > 0 or win_hi_h > 0
-    mask_w = plo_w > 0 or win_hi_w > 0
-
-    grid = (b, nh, nw, cout // tcout, cin // tcin)
-    body = functools.partial(
-        _sd_conv_body, kth=kth, ktw=ktw, th=th, tw=tw, h=h, w=wd,
-        osh=osh, osw=osw, pad_h=(plo_h, phi_h), pad_w=(plo_w, phi_w),
-        mask_h=mask_h, mask_w=mask_w)
+    cols = _round_sublanes(tw, x.dtype)
+    band_h, band_w = th + kth - 1, _round8(cols + ktw - 1)
+    # Output window row r reads padded rows from osh + r on.
+    xw = _window(x, (plo_h - osh, plo_w - osw),
+                 ((nh - 1) * th + band_h, (nw - 1) * tw + band_w))
+    quant = _is_int8_pair(x, w)
+    body = functools.partial(_sd_conv_body, kth=kth, ktw=ktw, th=th, tw=tw,
+                             cols=cols)
     return pl.pallas_call(
         body,
-        grid=grid,
+        grid=(b, nh, nw, cout // tcout, cin // tcin),
         in_specs=[
-            # Unblocked: the index map returns *element* offsets in the
-            # padded coordinate frame, which is what lets consecutive
-            # bands overlap by the halo AND reach into the zero padding.
-            pl.BlockSpec(
-                (1, th + kth - 1, tw + ktw - 1, tcin),
-                lambda bi, i, j, co, ci: (bi, i * th + osh, j * tw + osw,
-                                          ci * tcin),
-                indexing_mode=pl.Unblocked(
-                    ((0, 0), (plo_h, win_hi_h), (plo_w, win_hi_w),
-                     (0, 0)))),
-            pl.BlockSpec((kth, ktw, tcin, tcout),
-                         lambda bi, i, j, co, ci: (0, 0, ci, co)),
+            _band_spec(band_h, band_w, tcin, th, tw, nw, cin // tcin),
+            pl.BlockSpec((kth, ktw, 1, tcin, tcout),
+                         lambda bi, i, j, co, ci: (0, 0, 0, ci, co)),
         ],
         out_specs=pl.BlockSpec((1, th, tw, tcout),
                                lambda bi, i, j, co, ci: (bi, i, j, co)),
         out_shape=jax.ShapeDtypeStruct(
-            (b, oh, ow, cout),
-            jnp.int32 if _is_int8_pair(x, w) else x.dtype),
-        scratch_shapes=[pltpu.VMEM(
-            (th * tw, tcout),
-            jnp.int32 if _is_int8_pair(x, w) else jnp.float32)],
+            (b, oh, ow, cout), jnp.int32 if quant else x.dtype),
+        scratch_shapes=[pltpu.VMEM((1, th, cols, tcout),
+                                   jnp.int32 if quant else jnp.float32)],
         compiler_params=_compiler_params(4, 1),
         interpret=interpret,
-    )(x, w)
+    )(xw, w[:, :, None])
 
 
 # ---------------------------------------------------------------------------
-# Fused conv + interleave + epilogue kernel (in-kernel pad AND crop)
+# Fused conv + interleave + epilogue kernel (pad AND crop folded)
 # ---------------------------------------------------------------------------
 
-def _sd_fused_body(x_ref, w_ref, b_ref, *rest, kth: int,
-                   ktw: int, rh: int, rw: int, th: int, tw: int,
-                   sh: int, sw: int, res_h: int, res_w: int, act: str,
-                   h: int, w: int, q_h: int, q_w: int,
-                   pad_h: PadPair, pad_w: PadPair,
-                   mask_h: bool, mask_w: bool, quant: bool):
-    """Conv + in-VMEM stride-s interleave + crop-folded epilogue.
+def _sd_fused_body(x_ref, w_ref, b_ref, *rest, kth: int, ktw: int,
+                   rh: int, cols: int, th: int, tw: int, sh: int, sw: int,
+                   res_h: int, res_w: int, out_h: int, out_w: int,
+                   act: str, quant: bool):
+    """Conv + per-phase epilogue + strided-store interleave.
 
-    w_ref holds oc-major split filters: channel c = oc*sh*sw +
-    (py*sw + px), sliced to one TCout tile (TCout*sh*sw phase channels).
-    The step computes ``rh x rw`` conv rows (``th + 1`` when the residual
-    crop ``res`` is nonzero), the epilogue at the last cin tile
-    interleaves the sh*sw phases, adds the per-oc bias, applies the
-    activation, and writes the static slice ``[res : res + th*s)`` of
-    the interleaved tile — final output geometry, no HBM crop.
+    w_ref holds phase-major split filters: ``w_ref[kh, kw, p]`` is the
+    (TCin, TCout) matrix of phase ``p = py*sw + px``, so each phase
+    accumulates in its own (rh, cols, TCout) slab of ``acc_ref``.  The
+    step computes ``rh`` conv rows (``th + 1`` when the residual crop
+    ``res`` is nonzero) of ``cols >= tw (+ 1)`` conv columns.  The
+    epilogue at the last cin tile adds the per-oc bias and applies the
+    activation per phase, then stores phase (py, px) at rows
+    ``py - res_h (mod sh)`` + k*sh and cols ``px - res_w (mod sw)`` +
+    k*sw of the f32 tile ``y_ref`` — the interleave and the residual crop
+    in one strided store — and writes the tile once, cast to the output
+    dtype.
 
-    ``quant``: int8 launch — the accumulator is exact int32 and a
-    fourth operand carries the combined per-(sample, phase-channel)
-    dequant scale (activation scale x folded per-channel filter scale),
-    staged once per tile; the epilogue multiplies it into the int32
-    sums *before* the interleave (each phase channel has its own
-    scale), then runs the same bias + act + crop in f32.
+    ``quant``: int8 launch — the accumulator is exact int32 and a fourth
+    operand carries the combined per-(sample, phase, oc) dequant scale
+    (activation scale x folded per-channel filter scale), multiplied in
+    before the bias.
     """
     if quant:
-        s_ref, o_ref, acc_ref = rest
+        s_ref, o_ref, acc_ref, y_ref = rest
     else:
-        o_ref, acc_ref = rest
+        o_ref, acc_ref, y_ref = rest
     ci = pl.program_id(4)
 
     @pl.when(ci == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    xb = x_ref[0]
-    if mask_h or mask_w:
-        row0 = pl.program_id(1) * th + q_h
-        col0 = pl.program_id(2) * tw + q_w
-        xb = _mask_band(xb, row0, col0, h=h, w=w, pad_h=pad_h,
-                        pad_w=pad_w, mask_h=mask_h, mask_w=mask_w)
-    acc_ref[...] += _conv_partial(xb, w_ref[...], kth=kth, ktw=ktw,
-                                  rows=rh, cols=rw)
+    _accumulate(x_ref, w_ref, acc_ref, kth=kth, ktw=ktw, rows=rh,
+                cols=cols)
 
     @pl.when(ci == pl.num_programs(4) - 1)
     def _epilogue():
-        cphase = acc_ref.shape[-1]                 # TCout * sh*sw
-        tc = cphase // (sh * sw)
-        acc = acc_ref[...]
-        if quant:
-            # Dequant BEFORE the interleave: the (rh*rw, TCout*ss)
-            # int32 sums scale per phase channel (oc-major layout,
-            # matching w_ref), broadcast over the spatial rows.
-            acc = acc.astype(jnp.float32) * s_ref[0].astype(jnp.float32)
-        y = acc.reshape(rh, rw, tc, sh, sw)         # c -> (oc, py, px)
-        y = y.transpose(0, 3, 1, 4, 2)              # (rh, py, rw, px, oc)
-        y = y.reshape(rh * sh, rw * sw, tc)
-        y = y + b_ref[0].astype(jnp.float32)        # per-oc bias
-        y = _apply_act(y, act)
-        # Residual crop: a *static* slice of the interleaved VMEM tile.
-        y = y[res_h:res_h + th * sh, res_w:res_w + tw * sw]
-        if o_ref.dtype == jnp.int8:
-            # Chained launch: the next layer's 1/sx is already folded
-            # into scale+bias, so re-quantizing is a round + saturating
-            # clamp (never a wrapping cast) — the tile leaves VMEM as
-            # the next layer's int8 input, f32 never touches HBM.
-            y = jnp.clip(jnp.round(y), -127.0, 127.0)
-        o_ref[0] = y.astype(o_ref.dtype)
+        for py in range(sh):
+            for px in range(sw):
+                p = py * sw + px
+                # Phase rows land at py - res_h + k*sh; a negative start
+                # means the phase's first conv row is cropped away.
+                dy, dx = py - res_h, px - res_w
+                r0, c0 = int(dy < 0), int(dx < 0)
+
+                def phase(k, n, p=p, dy=dy, dx=dx, r0=r0, c0=c0):
+                    y = acc_ref[p, pl.ds(k + r0, n)].astype(jnp.float32)
+                    if quant:
+                        y = y * s_ref[0, p:p + 1, :]
+                    y = _apply_act(y + b_ref[...], act)
+                    y_ref[pl.ds(k * sh + dy % sh, n, stride=sh),
+                          pl.ds(dx % sw, tw, stride=sw), :] = (
+                              y[:, c0:c0 + tw])
+
+                _for_row_chunks(th, cols, phase)
+
+        def write(r, n):
+            y = y_ref[pl.ds(r, n), :out_w]
+            if o_ref.dtype == jnp.int8:
+                # Chained launch: the next layer's 1/sx is already folded
+                # into scale+bias, so re-quantizing is a round +
+                # saturating clamp (never a wrapping cast) — the tile
+                # leaves VMEM as the next layer's int8 input, f32 never
+                # touches HBM.
+                y = jnp.clip(jnp.round(y), -127.0, 127.0)
+            o_ref[0, pl.ds(r, n)] = y.astype(o_ref.dtype)
+
+        _for_row_chunks(out_h, tw * sw, write)
 
 
 def sd_fused_pallas(x: jax.Array, ws_ocmajor: jax.Array, s, *,
@@ -358,13 +363,15 @@ def sd_fused_pallas(x: jax.Array, ws_ocmajor: jax.Array, s, *,
                     crop: Tuple[int, int] = (0, 0),
                     out_space: Optional[Tuple[int, int]] = None,
                     out_dtype=None,
-                    interpret: bool = True) -> jax.Array:
-    """Fused SD: split-filter conv + interleaved (pixel-shuffled) write,
-    zero-copy end to end.
+                    interpret: bool) -> jax.Array:
+    """Fused SD: split-filter conv + interleaved (pixel-shuffled) write.
 
     x:  (B, H, W, Cin) — the *unpadded* input; ``pad`` (the ``P_I``
-        halo) is applied in kernel via border-masked element windows.
+        halo) is applied by the wrapper's one zero pad.
     ws_ocmajor: (KTh, KTw, Cin, Cout*sh*sw), channel c = oc*sh*sw + phase
+        (the plan's stored layout); the wrapper relays it phase-major
+        ``(KTh, KTw, sh*sw, Cin, Cout)`` for the kernel — a per-call
+        copy of the filters (see DESIGN.md "Memory traffic").
     s:  interleave factor — an int (square, the 2-D path) or an
         ``(sh, sw)`` pair (the 1-D lowering passes ``(1, s)``).
     bias: (Cout,) added per output channel in the epilogue (folded-BN
@@ -375,15 +382,14 @@ def sd_fused_pallas(x: jax.Array, ws_ocmajor: jax.Array, s, *,
           calibrated row shared by every sample).  Staged once per
           (batch, cout-tile) — the static row binds with a
           batch-independent index map — and multiplied into the int32
-          accumulator in the epilogue, before interleave/bias/act.
+          accumulator in the epilogue, before bias/act.
     crop: low-side crop per dim in interleaved coordinates (``P_K`` +
           user padding); folded into the launch as a ``c // s`` input
-          band offset plus a static ``c % s`` slice of the VMEM tile.
+          band offset plus a ``c % s`` phase offset of the stores.
     out_space: final output spatial shape (may extend past the shuffled
-          support — ``output_padding`` rows read fully-masked input and
-          come out as ``act(bias)``, matching the zero-extension
-          semantics).  Defaults to the uncropped interleave
-          ``s * (H + pad - KT + 1)``.
+          support — ``output_padding`` rows read zero padding and come
+          out as ``act(bias)``, matching the zero-extension semantics).
+          Defaults to the uncropped interleave ``s * (H + pad - KT + 1)``.
 
     returns (B, *out_space, Cout) — final deconv output geometry, one
     HBM write per element.  ``out_dtype`` defaults to ``x.dtype`` for
@@ -394,12 +400,13 @@ def sd_fused_pallas(x: jax.Array, ws_ocmajor: jax.Array, s, *,
     caller must have folded ``1/sx_next`` into ``scale`` and ``bias``).
     """
     sh, sw = (s, s) if isinstance(s, int) else (int(s[0]), int(s[1]))
+    ss = sh * sw
     b, h, wd, cin = x.shape
     kth, ktw = ws_ocmajor.shape[0], ws_ocmajor.shape[1]
-    cout = ws_ocmajor.shape[-1] // (sh * sw)
+    cout = ws_ocmajor.shape[-1] // ss
     quant = _is_int8_pair(x, ws_ocmajor)
     if quant and scale is None:
-        scale = jnp.ones((b, cout * sh * sw), jnp.float32)
+        scale = jnp.ones((b, cout * ss), jnp.float32)
     if not quant and scale is not None:
         raise ValueError("scale requires an int8 (x, ws) pair")
     if out_dtype is None:
@@ -412,9 +419,8 @@ def sd_fused_pallas(x: jax.Array, ws_ocmajor: jax.Array, s, *,
     full_ow = wd + plo_w + phi_w - ktw + 1
     oh, ow = (out_space if out_space is not None
               else (full_oh * sh, full_ow * sw))
-    c_h, c_w = crop
-    q_h, res_h = c_h // sh, c_h % sh
-    q_w, res_w = c_w // sw, c_w % sw
+    q_h, res_h = divmod(crop[0], sh)
+    q_w, res_w = divmod(crop[1], sw)
     tcout = tcout or cout
     tcin = tcin or cin
     assert cout % tcout == 0 and cin % tcin == 0
@@ -423,65 +429,55 @@ def sd_fused_pallas(x: jax.Array, ws_ocmajor: jax.Array, s, *,
     bias2d = bias.astype(jnp.float32).reshape(1, cout)
 
     th = min(th, _cdiv(oh, sh))
-    tw = tw or _cdiv(ow, sw)
-    tw = min(tw, _cdiv(ow, sw))
+    tw = min(tw or _cdiv(ow, sw), _cdiv(ow, sw))
     nh, nw = _cdiv(oh, th * sh), _cdiv(ow, tw * sw)
     rh = th + (1 if res_h else 0)             # conv rows per step
-    rw = tw + (1 if res_w else 0)
-    # Origin shift: the q whole-interleave-row crop means the first q
-    # padded rows/cols are never read — keep them out of the element
-    # window (q <= P_I by construction: c < s*K_T).
-    sh_h, sh_w = min(q_h, plo_h), min(q_w, plo_w)
-    plo_h, q_h = plo_h - sh_h, q_h - sh_h
-    plo_w, q_w = plo_w - sh_w, q_w - sh_w
-    # Element-window extents: band rows [i*th + q, i*th + q + rh+KTh-1)
-    # in padded coords; the high side covers residual + grid over-reach.
-    win_hi_h = max(0, (nh - 1) * th + q_h + rh + kth - 1 - (plo_h + h))
-    win_hi_w = max(0, (nw - 1) * tw + q_w + rw + ktw - 1 - (plo_w + wd))
-    mask_h = plo_h > 0 or win_hi_h > 0
-    mask_w = plo_w > 0 or win_hi_w > 0
+    cols = _round_sublanes(tw + (1 if res_w else 0), x.dtype)
+    band_h, band_w = rh + kth - 1, _round8(cols + ktw - 1)
+    # The q whole-interleave-row crop shifts the band origin by q rows.
+    xw = _window(x, (plo_h - q_h, plo_w - q_w),
+                 ((nh - 1) * th + band_h, (nw - 1) * tw + band_w))
+    wpm = ws_ocmajor.reshape(kth, ktw, cin, cout, ss).transpose(
+        0, 1, 4, 2, 3)
+    # A single-grid-step dim's output block is the whole (possibly
+    # odd-sized) dim; otherwise th*s | block offsets.
+    out_h, out_w = min(th * sh, oh), min(tw * sw, ow)
 
-    grid = (b, nh, nw, cout // tcout, cin // tcin)
     body = functools.partial(
-        _sd_fused_body, kth=kth, ktw=ktw, rh=rh, rw=rw, th=th, tw=tw,
-        sh=sh, sw=sw, res_h=res_h, res_w=res_w, act=act, h=h, w=wd,
-        q_h=q_h, q_w=q_w, pad_h=(plo_h, phi_h), pad_w=(plo_w, phi_w),
-        mask_h=mask_h, mask_w=mask_w, quant=quant)
-    ss = sh * sw
+        _sd_fused_body, kth=kth, ktw=ktw, rh=rh, cols=cols, th=th, tw=tw,
+        sh=sh, sw=sw, res_h=res_h, res_w=res_w, out_h=out_h, out_w=out_w,
+        act=act, quant=quant)
     in_specs = [
-        pl.BlockSpec(
-            (1, rh + kth - 1, rw + ktw - 1, tcin),
-            lambda bi, i, j, co, ci: (bi, i * th + q_h, j * tw + q_w,
-                                      ci * tcin),
-            indexing_mode=pl.Unblocked(
-                ((0, 0), (plo_h, win_hi_h), (plo_w, win_hi_w),
-                 (0, 0)))),
-        pl.BlockSpec((kth, ktw, tcin, tcout * ss),
-                     lambda bi, i, j, co, ci: (0, 0, ci, co)),
+        _band_spec(band_h, band_w, tcin, th, tw, nw, cin // tcin),
+        pl.BlockSpec((kth, ktw, ss, tcin, tcout),
+                     lambda bi, i, j, co, ci: (0, 0, 0, ci, co)),
         pl.BlockSpec((1, tcout), lambda bi, i, j, co, ci: (0, co)),
     ]
-    operands = [x, ws_ocmajor, bias2d]
+    operands = [xw, wpm, bias2d]
     if quant:
-        # Dequant scales: one (1, TCout*ss) row staged per (batch,
-        # cout-tile) grid step.  A single-row scale is the *static*
-        # calibrated case — bind it with a batch-independent index map
-        # so all samples share the one HBM row.
-        if scale.shape[0] == 1:
-            smap = lambda bi, i, j, co, ci: (0, co)
+        # Dequant scales, phase-major (B|1, ss, Cout): one (1, ss,
+        # TCout) block staged per (batch, cout-tile) grid step.  A
+        # single-row scale is the *static* calibrated case — bind it
+        # with a batch-independent index map so all samples share it.
+        srows = scale.astype(jnp.float32).reshape(-1, cout, ss)
+        srows = srows.transpose(0, 2, 1)
+        if srows.shape[0] == 1:
+            smap = lambda bi, i, j, co, ci: (0, 0, co)
         else:
-            smap = lambda bi, i, j, co, ci: (bi, co)
-        in_specs.append(pl.BlockSpec((1, tcout * ss), smap))
-        operands.append(scale.astype(jnp.float32))
+            smap = lambda bi, i, j, co, ci: (bi, 0, co)
+        in_specs.append(pl.BlockSpec((1, ss, tcout), smap))
+        operands.append(srows)
     return pl.pallas_call(
         body,
-        grid=grid,
+        grid=(b, nh, nw, cout // tcout, cin // tcin),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, th * sh, tw * sw, tcout),
+        out_specs=pl.BlockSpec((1, out_h, out_w, tcout),
                                lambda bi, i, j, co, ci: (bi, i, j, co)),
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, cout), out_dtype),
-        scratch_shapes=[pltpu.VMEM(
-            (rh * rw, tcout * ss),
-            jnp.int32 if quant else jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((ss, rh, cols, tcout),
+                       jnp.int32 if quant else jnp.float32),
+            pltpu.VMEM((th * sh, tw * sw, tcout), jnp.float32)],
         compiler_params=_compiler_params(4, 1),
         interpret=interpret,
     )(*operands)
@@ -491,34 +487,34 @@ def sd_fused_pallas(x: jax.Array, ws_ocmajor: jax.Array, s, *,
 # Filter-gradient kernel (the SD backward's second stride-1 conv)
 # ---------------------------------------------------------------------------
 
-def _sd_filter_grad_body(x_ref, dy_ref, o_ref, acc_ref, *, ktw: int,
-                         o1h: int, o1w: int, h: int, w: int,
-                         pad_h: PadPair, pad_w: PadPair,
-                         mask_h: bool, mask_w: bool):
-    """One (tap, cin-tile, cout-tile, batch) grid step: a single MXU GEMM
-    ``(TCin, O1h*O1w) x (O1h*O1w, TCout)`` accumulated over the batch."""
-    bi = pl.program_id(3)
+def _sd_filter_grad_body(x_ref, dy_ref, o_ref, acc_ref, *, kth: int,
+                         ktw: int, tr: int, cols: int):
+    """One (cin-tile, cout-tile, batch, row-band) grid step: per tap and
+    row chunk one MXU GEMM ``(TCin, n*cols) x (n*cols, TCout)``,
+    accumulated over the batch and the row bands."""
+    first = (pl.program_id(2) == 0) & (pl.program_id(3) == 0)
+    last = ((pl.program_id(2) == pl.num_programs(2) - 1)
+            & (pl.program_id(3) == pl.num_programs(3) - 1))
 
-    @pl.when(bi == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    xb = x_ref[0]
-    if mask_h or mask_w:
-        tap = pl.program_id(0)
-        xb = _mask_band(xb, tap // ktw, tap % ktw, h=h, w=w,
-                        pad_h=pad_h, pad_w=pad_w,
-                        mask_h=mask_h, mask_w=mask_w)
-    m = o1h * o1w
-    lhs = xb.reshape(m, xb.shape[-1]).astype(jnp.float32)
-    rhs = dy_ref[0].reshape(m, dy_ref.shape[-1]).astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot_general(
-        lhs, rhs, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    tcin, tco = x_ref.shape[-1], dy_ref.shape[-1]
 
-    @pl.when(bi == pl.num_programs(3) - 1)
+    def step(r, n):
+        dy = dy_ref[0, pl.ds(r, n)].reshape(n * cols, tco)
+        for kh in range(kth):
+            for kw in range(ktw):
+                lhs = x_ref[0, pl.ds(r + kh, n), kw:kw + cols, :]
+                acc_ref[kh, kw] += _dot(lhs.reshape(n * cols, tcin), dy,
+                                        contract=0)
+
+    _for_row_chunks(tr, cols, step)
+
+    @pl.when(last)
     def _write():
-        o_ref[0, 0] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 def sd_filter_grad_pallas(x: jax.Array, dy1: jax.Array,
@@ -526,21 +522,21 @@ def sd_filter_grad_pallas(x: jax.Array, dy1: jax.Array,
                           pad: Tuple[PadPair, PadPair] = ((0, 0), (0, 0)),
                           tcout: int | None = None,
                           tcin: int | None = None,
-                          interpret: bool = True) -> jax.Array:
+                          tr: int | None = None,
+                          interpret: bool) -> jax.Array:
     """VJP of ``y1 = conv_valid_stride1(pad(x), ws)`` w.r.t. ``ws``.
 
-    x: (B, H, W, Cin) *unpadded* — the logical ``P_I`` pad is applied in
-    kernel (border-masked element window), so the padded activation copy
-    the XLA formulation materialises never exists.
-    dy1: (B, O1h, O1w, NCo) cotangent of the split conv output, with
-    O1 = H + pad - KT + 1 per dim.
+    x: (B, H, W, Cin) *unpadded* — the logical ``P_I`` pad is applied by
+    the wrapper's one zero pad.  dy1: (B, O1h, O1w, NCo) cotangent of
+    the split conv output, with O1 = H + pad - KT + 1 per dim.
     Returns dws: (KTh, KTw, Cin, NCo).
 
     The conv's taps are ``dy1``'s spatial extent (large), and its output
     extent is ``KT`` (tiny) — the roles are inverted vs the forward
-    kernel, so each grid step is ONE big GEMM contracting over
-    ``O1h*O1w`` with an f32 accumulator carried over the batch axis
-    (innermost, ``arbitrary``).
+    kernel, so each grid step runs one big GEMM per tap contracting over
+    a band of ``tr`` rows of ``dy1`` (default: all ``O1h``), with an f32
+    accumulator carried over the batch and row-band axes (innermost,
+    ``arbitrary``).
     """
     b, h, wd, cin = x.shape
     kth, ktw = kt
@@ -550,35 +546,33 @@ def sd_filter_grad_pallas(x: jax.Array, dy1: jax.Array,
     assert o1w == wd + plo_w + phi_w - ktw + 1, (o1w, wd, pad, ktw)
     tcout = tcout or nco
     tcin = tcin or cin
+    tr = min(tr or o1h, o1h)
     assert nco % tcout == 0 and cin % tcin == 0
-    mask_h = plo_h > 0 or phi_h > 0
-    mask_w = plo_w > 0 or phi_w > 0
-
-    grid = (kth * ktw, cin // tcin, nco // tcout, b)
-    body = functools.partial(
-        _sd_filter_grad_body, ktw=ktw, o1h=o1h, o1w=o1w, h=h, w=wd,
-        pad_h=(plo_h, phi_h), pad_w=(plo_w, phi_w),
-        mask_h=mask_h, mask_w=mask_w)
+    nr = _cdiv(o1h, tr)
+    # Zero rows/cols past O1 (row bands that do not divide O1h, columns
+    # rounded to the sublane tile) contribute nothing to the sums.
+    cols = _round_sublanes(o1w, jnp.promote_types(x.dtype, dy1.dtype))
+    xw = _window(x, (plo_h, plo_w), (nr * tr + kth - 1,
+                                      _round8(cols + ktw - 1)))
+    dyw = _window(dy1, (0, 0), (nr * tr, cols))
+    body = functools.partial(_sd_filter_grad_body, kth=kth, ktw=ktw,
+                             tr=tr, cols=cols)
     return pl.pallas_call(
         body,
-        grid=grid,
+        grid=(cin // tcin, nco // tcout, b, nr),
         in_specs=[
-            # Tap d reads padded rows [d//ktw, d//ktw + O1h) — always
-            # inside the padded frame, so the window needs no extra
-            # high-side growth.
             pl.BlockSpec(
-                (1, o1h, o1w, tcin),
-                lambda d, ci, co, bi: (bi, d // ktw, d % ktw, ci * tcin),
-                indexing_mode=pl.Unblocked(
-                    ((0, 0), (plo_h, phi_h), (plo_w, phi_w), (0, 0)))),
-            pl.BlockSpec((1, o1h, o1w, tcout),
-                         lambda d, ci, co, bi: (bi, 0, 0, co)),
+                (pl.Element(1), pl.Element(tr + kth - 1),
+                 pl.Element(xw.shape[2]), pl.Element(tcin)),
+                lambda ci, co, bi, r: (bi, r * tr, 0,
+                                       ci * tcin if tcin < cin else 0)),
+            pl.BlockSpec((1, tr, cols, tcout),
+                         lambda ci, co, bi, r: (bi, r, 0, co)),
         ],
-        out_specs=pl.BlockSpec((1, 1, tcin, tcout),
-                               lambda d, ci, co, bi: (d // ktw, d % ktw,
-                                                      ci, co)),
+        out_specs=pl.BlockSpec((kth, ktw, tcin, tcout),
+                               lambda ci, co, bi, r: (0, 0, ci, co)),
         out_shape=jax.ShapeDtypeStruct((kth, ktw, cin, nco), dy1.dtype),
-        scratch_shapes=[pltpu.VMEM((tcin, tcout), jnp.float32)],
-        compiler_params=_compiler_params(3, 1),
+        scratch_shapes=[pltpu.VMEM((kth, ktw, tcin, tcout), jnp.float32)],
+        compiler_params=_compiler_params(2, 2),
         interpret=interpret,
-    )(x, dy1)
+    )(xw, dyw)

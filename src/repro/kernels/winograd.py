@@ -20,7 +20,7 @@ This module owns all three pieces and where they run:
   transformed filters (layout ``"wino"``); the hot path never sees
   ``G``.
 * **input transform** ``V = B^T d B`` — inside the Pallas kernel, on the
-  VMEM band, reusing the zero-copy masked-window machinery of
+  VMEM band, reusing the padded input window of
   :mod:`repro.kernels.sd_conv` (the band for ``nt`` output tiles spans
   ``nt*m + K_T - 1`` rows — the same halo shape as the direct kernel).
 * **inverse output transform** ``Y = A^T M A`` — folded into the
@@ -53,8 +53,8 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .sd_conv import (PadPair, _apply_act, _cdiv, _compiler_params,
-                      _mask_band)
+from .sd_conv import (PadPair, _apply_act, _band_spec, _cdiv,
+                      _compiler_params, _window)
 
 # Output tile size per dim.  m=2 is the sweet spot for the small K_T the
 # split produces: larger m needs more evaluation points, whose spread is
@@ -190,17 +190,15 @@ def _mat_rows(mat: np.ndarray, rows: list) -> list:
 def _wino_fused_body(x_ref, u_ref, b_ref, o_ref, acc_ref, *, kth: int,
                      ktw: int, mh: int, mw: int, nth: int, ntw: int,
                      rh: int, rw: int, th: int, tw: int, sh: int,
-                     sw: int, res_h: int, res_w: int, act: str, h: int,
-                     w: int, q_h: int, q_w: int, pad_h: PadPair,
-                     pad_w: PadPair, mask_h: bool, mask_w: bool,
+                     sw: int, res_h: int, res_w: int, act: str,
                      bt_h: np.ndarray, bt_w: np.ndarray,
                      at_h: np.ndarray, at_w: np.ndarray):
     """One (batch, row-tile, col-tile, cout-tile, cin-tile) grid step.
 
     The band covers ``nth x ntw`` Winograd tiles = ``nth*mh`` conv rows
     (``>= rh``, the rows the direct kernel would compute) with the same
-    ``(K_T - 1)`` halo — so the element window, origin shift and border
-    masks are exactly the direct kernel's.  Per cin tile the body
+    ``(K_T - 1)`` halo — so the padded input window is exactly the
+    direct kernel's.  Per cin tile the body
     extracts the ``alpha``-wide tile windows (static strided slices),
     applies ``B^T d B`` (two small VPU matmuls), and accumulates the
     ``alpha_h*alpha_w`` batched MXU matmuls ``V[k] @ U[k]`` into the
@@ -215,11 +213,6 @@ def _wino_fused_body(x_ref, u_ref, b_ref, o_ref, acc_ref, *, kth: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     xb = x_ref[0]
-    if mask_h or mask_w:
-        row0 = pl.program_id(1) * th + q_h
-        col0 = pl.program_id(2) * tw + q_w
-        xb = _mask_band(xb, row0, col0, h=h, w=w, pad_h=pad_h,
-                        pad_w=pad_w, mask_h=mask_h, mask_w=mask_w)
     ah, aw = mh + kth - 1, mw + ktw - 1
     xb = xb.astype(jnp.float32)
     tcin = xb.shape[-1]
@@ -272,9 +265,9 @@ def sd_wino_pallas(x: jax.Array, u: jax.Array, kt: Tuple[int, int], s,
                    pad: Tuple[PadPair, PadPair] = ((0, 0), (0, 0)),
                    crop: Tuple[int, int] = (0, 0),
                    out_space: Optional[Tuple[int, int]] = None,
-                   out_dtype=None, interpret: bool = True) -> jax.Array:
+                   out_dtype=None, interpret: bool) -> jax.Array:
     """Fused Winograd SD: transformed-domain split conv + interleaved
-    write, zero-copy end to end — the Winograd sibling of
+    write — the Winograd sibling of
     :func:`repro.kernels.sd_conv.sd_fused_pallas` (same contract; see
     its docstring for the pad/crop/out_space semantics).
 
@@ -316,16 +309,9 @@ def sd_wino_pallas(x: jax.Array, u: jax.Array, kt: Tuple[int, int], s,
     nth, ntw = _cdiv(rh, mh), _cdiv(rw, mw)   # Winograd tiles per step
     band_h = nth * mh + kth - 1               # computed rows + halo
     band_w = ntw * mw + ktw - 1
-    # Origin shift — identical to the direct kernel.
-    sh_h, sh_w = min(q_h, plo_h), min(q_w, plo_w)
-    plo_h, q_h = plo_h - sh_h, q_h - sh_h
-    plo_w, q_w = plo_w - sh_w, q_w - sh_w
-    # Element-window extents: the tile round-up (nt*m >= rh) joins the
-    # residual + grid over-reach on the high side; all masked.
-    win_hi_h = max(0, (nh - 1) * th + q_h + band_h - (plo_h + h))
-    win_hi_w = max(0, (nw - 1) * tw + q_w + band_w - (plo_w + wd))
-    mask_h = plo_h > 0 or win_hi_h > 0
-    mask_w = plo_w > 0 or win_hi_w > 0
+    # The same padded input window as the direct kernel.
+    xw = _window(x, (plo_h - q_h, plo_w - q_w),
+                 ((nh - 1) * th + band_h, (nw - 1) * tw + band_w))
 
     at_h, _, bt_h = winograd_matrices(mh, kth)
     at_w, _, bt_w = winograd_matrices(mw, ktw)
@@ -333,21 +319,13 @@ def sd_wino_pallas(x: jax.Array, u: jax.Array, kt: Tuple[int, int], s,
     body = functools.partial(
         _wino_fused_body, kth=kth, ktw=ktw, mh=mh, mw=mw, nth=nth,
         ntw=ntw, rh=rh, rw=rw, th=th, tw=tw, sh=sh, sw=sw, res_h=res_h,
-        res_w=res_w, act=act, h=h, w=wd, q_h=q_h, q_w=q_w,
-        pad_h=(plo_h, phi_h), pad_w=(plo_w, phi_w), mask_h=mask_h,
-        mask_w=mask_w, bt_h=bt_h, bt_w=bt_w, at_h=at_h, at_w=at_w)
+        res_w=res_w, act=act, bt_h=bt_h, bt_w=bt_w, at_h=at_h, at_w=at_w)
     ss = sh * sw
     return pl.pallas_call(
         body,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(
-                (1, band_h, band_w, tcin),
-                lambda bi, i, j, co, ci: (bi, i * th + q_h,
-                                          j * tw + q_w, ci * tcin),
-                indexing_mode=pl.Unblocked(
-                    ((0, 0), (plo_h, win_hi_h), (plo_w, win_hi_w),
-                     (0, 0)))),
+            _band_spec(band_h, band_w, tcin, th, tw, nw, cin // tcin),
             pl.BlockSpec((ah, aw, tcin, tcout * ss),
                          lambda bi, i, j, co, ci: (0, 0, ci, co)),
             pl.BlockSpec((1, tcout), lambda bi, i, j, co, ci: (0, co)),
@@ -359,4 +337,4 @@ def sd_wino_pallas(x: jax.Array, u: jax.Array, kt: Tuple[int, int], s,
             (ah * aw * nth * ntw, tcout * ss), jnp.float32)],
         compiler_params=_compiler_params(4, 1),
         interpret=interpret,
-    )(x, u, bias2d)
+    )(xw, u, bias2d)

@@ -1,11 +1,10 @@
-"""Mesh construction — the one place jax version compat lives.
+"""Mesh construction.
 
 Every mesh in the repo (serving's (data, model) dev mesh, the sharded
-train step's, the production topology) comes from :func:`make_mesh`, so
-the ``AxisType`` compat shim exists exactly once.  Defined as functions
-(never module-level constants) so importing this module never touches
-jax device state — required because the dry-run must set XLA_FLAGS
-before the first jax initialisation.
+train step's, the production topology) comes from :func:`make_mesh`.
+Defined as functions (never module-level constants) so importing this
+module never touches jax device state — required because the dry-run
+must set XLA_FLAGS before the first jax initialisation.
 """
 
 from __future__ import annotations
@@ -14,16 +13,13 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across versions: ``axis_types`` (and
-    ``AxisType``) only exist on newer jax; Auto is the default there
-    anyway.  ``shape`` entries must multiply to a divisor-compatible
+    """``jax.make_mesh`` with Auto axes: XLA propagates shardings, which
+    is what ``shard_map`` and the ``NamedSharding`` placement here
+    expect.  ``shape`` entries must multiply to a divisor-compatible
     device count — callers validate availability (e.g. serve_gen checks
     ``dp * mp <= jax.device_count()``) before landing here."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -146,7 +146,8 @@ class GenerativeModel:
             last = i == len(layers) - 1
             if layer.kind == "fc":
                 h = h.reshape(h.shape[0], -1)
-                h = h @ p["w"] + p["b"]
+                h = jnp.matmul(h, p["w"],
+                               precision=jax.lax.Precision.HIGHEST) + p["b"]
                 # reshape for the next spatial layer (any rank)
                 nxt = layers[i + 1] if i + 1 < len(layers) else None
                 if nxt is not None and nxt.kind != "fc":

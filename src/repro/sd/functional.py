@@ -56,9 +56,9 @@ def _run_presplit(plan: DeconvPlan, x: jax.Array, ws: jax.Array,
                   layout: str, bias: Optional[jax.Array],
                   act: str) -> jax.Array:
     """Dispatch pre-split filters to the plan's execution backend,
-    any rank: the zero-copy fused Pallas kernel for ranks 1-2 (1-D
-    lowers as H=1 2-D; the P_I pad and P_K/user crop live inside the
-    kernel, so this path touches HBM once per tensor), the depth-folded
+    any rank: the fused Pallas kernel for ranks 1-2 (1-D lowers as H=1
+    2-D; the P_K/user crop lives inside the kernel, so the output is
+    written once), the depth-folded
     Pallas + grouped-XLA interleave for rank 3, and the grouped-XLA
     conv + pixel-shuffle for the xla backend.  The winograd backend
     runs the F(2,r) fast-algorithm Pallas kernel: a bound plan
@@ -139,7 +139,7 @@ def _run_presplit_int8(plan: DeconvPlan, x: jax.Array) -> jax.Array:
     re-quantizes the activated tile to int8 in VMEM, so the
     inter-layer tensor lives in HBM as int8.
 
-    The fused backend does all of this inside the zero-copy Pallas
+    The fused backend does all of this inside the fused Pallas
     kernel (int32 VMEM accumulator, scale staged once per tile — one
     static row for calibrated plans).  The xla backend keeps the same
     quantization numerics but computes the conv on f32-cast operands —

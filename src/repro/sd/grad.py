@@ -33,11 +33,11 @@ Each step is linear, so the VJP is the chain of adjoints, right to left:
 * pad^T       — crop the ``P_I`` halo off the input grad.
 
 Kernel routing.  For a ``backend="fused"`` plan of rank 1 or 2 the two
-convolutions above run through the zero-copy Pallas kernels
+convolutions above run through the Pallas kernels
 (:func:`repro.kernels.ops.sd_input_grad_fused` — the FULL-conv pad is
-border-masked halo reads and the pad^T crop is the launch's output
+the launch's zero pad and the pad^T crop is the launch's output
 window — and :func:`repro.kernels.ops.sd_filter_grad_fused`, whose
-``P_I`` activation pad is in kernel so ``xp`` never materialises),
+launch applies the ``P_I`` activation pad),
 each under its own tagged ``ConvGeom`` autotune key; 1-D lowers as H=1
 2-D exactly like the forward.  The fused backend is therefore trainable
 on-kernel, not just differentiable-by-fallback.  ``backend="xla"`` (and

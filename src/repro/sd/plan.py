@@ -237,7 +237,10 @@ class DeconvPlan:
         if shards > 1 and self.cout % shards:
             raise ValueError(
                 f"cout {self.cout} not divisible by {shards} shards")
-        return replace(self, shards=shards,
+        # A tile resolved for another Cout need not divide this one's
+        # shard: re-resolve at launch from the local geometry.
+        tile = self.tile if shards == self.shards else None
+        return replace(self, shards=shards, tile=tile,
                        shard_axis=self.shard_axis if axis is None
                        else str(axis))
 

@@ -4,9 +4,11 @@ import json
 
 import pytest
 
-from repro.kernels.autotune import (ConvGeom, KernelPlan, candidate_plans,
-                                    get_plan, heuristic_plan, load_cache,
-                                    measure, save_cache, tune)
+from repro.kernels.autotune import (VMEM_BUDGET, ConvGeom, KernelPlan,
+                                    candidate_plans, get_plan,
+                                    heuristic_plan, lane_tiles, load_cache,
+                                    measure, save_cache, tune,
+                                    vmem_plan_bytes)
 
 GEOMS = [
     ConvGeom(1, 12, 12, 256, 128, 3, 2),    # DCGAN d1 (padded)
@@ -22,8 +24,10 @@ def test_heuristic_plan_valid(geom):
     assert p.th >= 1
     assert geom.cin % p.tcin == 0
     assert geom.cout % p.tcout == 0
-    # the accumulator + filter block must stay VMEM-sized
-    assert geom.kt ** 2 * p.tcin * p.tcout * geom.s ** 2 * 4 <= 2 << 20
+    # channel tiles Mosaic can lay out, and a step that fits VMEM
+    assert p.tcin in lane_tiles(geom.cin)
+    assert p.tcout in lane_tiles(geom.cout)
+    assert vmem_plan_bytes(geom, p) <= VMEM_BUDGET
 
 
 def test_heuristic_no_th1_collapse():
@@ -83,6 +87,21 @@ def test_tune_skips_failing_candidates(tmp_path):
 
     assert tune(geom, runner, candidates=[KernelPlan(8, 16, 8), good],
                 path=cache) == good
+
+
+def test_tune_raises_when_every_candidate_fails(tmp_path):
+    """A backend that refuses every tile must surface its error, not
+    hand back the heuristic plan as if it had been measured."""
+    cache = str(tmp_path / "plans.json")
+    geom = ConvGeom(1, 12, 12, 16, 8, 3, 2)
+
+    def runner(plan):
+        raise RuntimeError(f"Mosaic failed to compile {plan}")
+
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        tune(geom, runner, candidates=[KernelPlan(8, 16, 8),
+                                       KernelPlan(4, 16, 8)], path=cache)
+    assert geom.key() not in load_cache(cache)
 
 
 def test_get_plan_falls_back_on_invalid_cache_entry(tmp_path):

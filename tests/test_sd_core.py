@@ -8,7 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import assume, given, settings, st
+from hypothesis import assume, given, settings
+from hypothesis import strategies as st
 
 from repro.core import (chang_deconv, deconv_output_shape, depth_to_space,
                         dilate_input, native_deconv, nzp_deconv,

@@ -4,7 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.models.ssm import (MLSTMState, init_mamba, init_mamba_state,
                               init_mlstm, init_mlstm_state, init_slstm,

@@ -276,10 +276,12 @@ def test_conv_geom_key_distinct_per_algo():
 def test_wino_vmem_model_larger_than_direct():
     """The winograd footprint model charges the alpha-expanded filter
     block and the transformed-domain accumulator — a wino launch of the
-    same tile is never modelled smaller than the direct one."""
-    g = ConvGeom.from_deconv(1, 8, 8, 64, 32, 4, 2, padding=1)
+    same tile is never modelled smaller than the direct one.  At full
+    128-lane channel tiles: narrower ones pad each phase of the direct
+    kernel's phase-major filter block to 128 lanes."""
+    g = ConvGeom.from_deconv(1, 8, 8, 128, 128, 4, 2, padding=1)
     gw = dataclasses.replace(g, algo="wino")
-    p = KernelPlan(th=4, tcin=64, tcout=32)
+    p = KernelPlan(th=4, tcin=128, tcout=128)
     assert (autotune.vmem_plan_bytes(gw, p)
             > autotune.vmem_plan_bytes(g, p))
 

@@ -319,17 +319,19 @@ def test_zero_copy_empty_output_dim():
 
 
 def test_filter_grad_channel_tiles_fit_vmem():
-    """Unpinned filter-grad launches clamp channel tiles to the dw
-    kernel's own footprint (full-O1 blocks), not the conv-band model —
-    wide layers must not resolve to full channel depth."""
+    """Unpinned filter-grad launches size their tiles to the dw kernel's
+    own footprint (a band of padded input rows at full width plus the
+    same rows of dy1), not the conv-band model — wide layers must not
+    resolve to full channel depth and all O1 rows."""
     from repro.kernels.autotune import VMEM_BUDGET
-    from repro.kernels.ops import _dw_fit_channels
-    o1 = 130 * 130                        # fst/artgan-scale extent
-    tcin, tcout = _dw_fit_channels(o1, 128, 256)
-    assert 4 * (o1 * tcin + o1 * tcout + 2 * tcin * tcout) <= VMEM_BUDGET
-    assert 128 % tcin == 0 and 256 % tcout == 0
+    from repro.kernels.ops import _dw_tiles
+    o1, wp = (130, 130), 132              # fst/artgan-scale extent
+    tr, tcin, tcout = _dw_tiles(o1, wp, (3, 3), 128, 256)
+    assert (tr, tcin, tcout) != (130, 128, 256)
+    assert 4 * ((tr + 2) * wp * tcin + tr * o1[1] * tcout) <= VMEM_BUDGET
+    assert tcin == 128 and tcout in (128, 256)      # 128-lane tiles
     # and grads stay exact under the clamped tiling (forced small
-    # budget exercises multi-tile channel accumulation)
+    # budget exercises multi-band row accumulation)
     import repro.kernels.autotune as at
     rng = np.random.RandomState(9)
     x = jnp.asarray(rng.randn(2, 6, 5, 8), jnp.float32)
@@ -339,7 +341,7 @@ def test_filter_grad_channel_tiles_fit_vmem():
                                 plan=KernelPlan(th=1, tcin=8, tcout=12))
     orig = at.VMEM_BUDGET
     try:
-        at.VMEM_BUDGET = 1 << 12          # force tiny channel tiles
+        at.VMEM_BUDGET = 1 << 12          # force one-row bands
         got = sd_filter_grad_fused(x, dy1, kt, pi)
     finally:
         at.VMEM_BUDGET = orig
