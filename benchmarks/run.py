@@ -3,17 +3,13 @@
   PYTHONPATH=src python -m benchmarks.run            # everything
   PYTHONPATH=src python -m benchmarks.run --only tables123,procmodel
   PYTHONPATH=src python -m benchmarks.run --json out.json   # + JSON dump
-  PYTHONPATH=src python -m benchmarks.run --profile /tmp/tr  # + traces
 
 shard_bench needs four devices: on a CPU host run the driver under
 XLA_FLAGS=--xla_force_host_platform_device_count=4.
 """
 
 import argparse
-import contextlib
 import json
-import os
-import sys
 import time
 
 
@@ -68,10 +64,6 @@ def main() -> None:
                     help="comma-separated module names")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="dump every report table as JSON to PATH")
-    ap.add_argument("--profile", default=None, metavar="DIR",
-                    help="capture one jax.profiler trace per suite "
-                         "under DIR/<suite> (view with tensorboard or "
-                         "perfetto)")
     args = ap.parse_args()
 
     from benchmarks import (commodity, kernel_bench, loadgen, nd_bench,
@@ -90,18 +82,8 @@ def main() -> None:
     t0 = time.time()
     for name in wanted:
         t1 = time.time()
-        if args.profile:
-            import jax
-            tdir = os.path.join(args.profile, name)
-            os.makedirs(tdir, exist_ok=True)
-            ctx = jax.profiler.trace(tdir)
-        else:
-            ctx = contextlib.nullcontext()
-        with ctx:
-            mods[name].run(report)
-        print(f"  [{name}: {time.time()-t1:.1f}s]"
-              + (f" trace -> {os.path.join(args.profile, name)}"
-                 if args.profile else ""))
+        mods[name].run(report)
+        print(f"  [{name}: {time.time()-t1:.1f}s]")
     if args.json:
         report.dump_json(args.json)
         print(f"report tables dumped to {args.json}")

@@ -150,6 +150,7 @@ class GenServer:
         self._serving: Dict[str, Tuple[Any, Any, Any]] = {}
         self._compiled: Dict[Tuple, Any] = {}
         self.compile_count = 0          # incremented at trace time
+        self.group_ms: Dict[str, float] = {}   # last run_group's phases
         self._mesh = None
         if self.dp > 1 or self.mp > 1:
             need = self.dp * self.mp
@@ -346,16 +347,28 @@ class GenServer:
 
     # ---- serving ---------------------------------------------------------
     def run_group(self, net: str, latents: List[Any]):
-        """Pad a same-net group to its bucket, run, crop the padding."""
+        """Pad a same-net group to its bucket, run, crop the padding.
+
+        Its two phases are profiler spans, and their host ms are left in
+        ``group_ms`` for the scheduler's launch record: ``serve.inputs``
+        (``inputs_ms``: per-request copies, stack, pad) and
+        ``serve.dispatch`` (``dispatch_ms``: the compiled call and the
+        crop, enqueued, not waited on)."""
         n = len(latents)
         bucket = self.bucket(n)
         lean_params, plans = self._serving_args(net, bucket)
-        x = jnp.stack([jnp.asarray(z, self.dtype) for z in latents])
-        if bucket > n:
-            pad = jnp.zeros((bucket - n, *x.shape[1:]), self.dtype)
-            x = jnp.concatenate([x, pad])
-        y = self.compiled(net, bucket)(lean_params, plans, x)
-        return y[:n]
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("serve.inputs"):
+            x = jnp.stack([jnp.asarray(z, self.dtype) for z in latents])
+            if bucket > n:
+                pad = jnp.zeros((bucket - n, *x.shape[1:]), self.dtype)
+                x = jnp.concatenate([x, pad])
+        t1 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("serve.dispatch"):
+            y = self.compiled(net, bucket)(lean_params, plans, x)[:n]
+        self.group_ms = {"inputs_ms": (t1 - t0) * 1e3,
+                         "dispatch_ms": (time.perf_counter() - t1) * 1e3}
+        return y
 
     def serve(self, requests: List[GenRequest]):
         """LEGACY drain-the-group loop: partitions the whole queue into

@@ -142,26 +142,29 @@ class GenerativeModel:
         layers = self.spec.layers
         h = x
         for i, layer in enumerate(layers):
-            p = params.get(layer.name)   # deconv steps may not need it
-            last = i == len(layers) - 1
-            if layer.kind == "fc":
-                h = h.reshape(h.shape[0], -1)
-                h = jnp.matmul(h, p["w"],
-                               precision=jax.lax.Precision.HIGHEST) + p["b"]
-                # reshape for the next spatial layer (any rank)
-                nxt = layers[i + 1] if i + 1 < len(layers) else None
-                if nxt is not None and nxt.kind != "fc":
-                    h = h.reshape(h.shape[0], *nxt.in_hw, nxt.cin)
-            elif layer.kind == "conv":
-                pads = "SAME" if layer.padding == "same" else layer.pad
-                h = conv_nd(h, p["w"], layer.s, pads)
-                h = h * p["scale"] + p["b"]
-            else:                        # deconv: strategy-dependent
-                h, epilogue_done = deconv_step(layer, p, h)
-                if epilogue_done:
-                    continue
-            if not last:
-                h = jax.nn.relu(h)
+            # One scope per layer names its device ops in a profile.
+            with jax.named_scope(layer.name):
+                p = params.get(layer.name)   # deconv steps may not need it
+                last = i == len(layers) - 1
+                if layer.kind == "fc":
+                    h = h.reshape(h.shape[0], -1)
+                    h = jnp.matmul(
+                        h, p["w"],
+                        precision=jax.lax.Precision.HIGHEST) + p["b"]
+                    # reshape for the next spatial layer (any rank)
+                    nxt = layers[i + 1] if i + 1 < len(layers) else None
+                    if nxt is not None and nxt.kind != "fc":
+                        h = h.reshape(h.shape[0], *nxt.in_hw, nxt.cin)
+                elif layer.kind == "conv":
+                    pads = "SAME" if layer.padding == "same" else layer.pad
+                    h = conv_nd(h, p["w"], layer.s, pads)
+                    h = h * p["scale"] + p["b"]
+                else:                        # deconv: strategy-dependent
+                    h, epilogue_done = deconv_step(layer, p, h)
+                    if epilogue_done:
+                        continue
+                if not last:
+                    h = jax.nn.relu(h)
         return jnp.tanh(h) if self.final_tanh else h
 
     def apply(self, params: Params, x: jax.Array) -> jax.Array:

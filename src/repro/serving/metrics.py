@@ -10,7 +10,10 @@ the legacy drain loop) and records three event streams:
   expired, or the estimated service time of its launch could not meet
   it).  Shed requests never enter the latency percentiles; they show up
   in ``shed_rate`` and subtract from goodput instead,
-* **launches** — one executed bucket: ``(net, bucket, n, ms)``.  The
+* **launches** — one executed bucket: ``(net, bucket, n, ms)``, with
+  the host ms of the scheduler's ``sched.outputs`` span
+  (``outputs_ms``) and of the server's own phases where it reports
+  them (``GenServer.group_ms``: ``inputs_ms``, ``dispatch_ms``).  The
   occupancy histogram (how full each launched bucket was) is the
   continuous-batching health signal: a drain loop shows trailing
   1-of-16 buckets, the scheduler should keep buckets near full under
@@ -65,10 +68,12 @@ class ServingMetrics:
     def record_shed(self, rid: int, net: str, reason: str) -> None:
         self.shed.append({"rid": rid, "net": net, "reason": reason})
 
-    def record_launch(self, net: str, bucket: int, n: int,
-                      ms: float) -> None:
-        self.launches.append({"net": net, "bucket": int(bucket),
-                              "n": int(n), "ms": ms})
+    def record_launch(self, net: str, bucket: int, n: int, ms: float,
+                      **phase_ms: float) -> dict:
+        rec = {"net": net, "bucket": int(bucket), "n": int(n), "ms": ms,
+               **phase_ms}
+        self.launches.append(rec)
+        return rec
 
     # ---- derived ---------------------------------------------------------
     def _latency_block(self, lats: List[float]) -> dict:
@@ -89,16 +94,12 @@ class ServingMetrics:
             b[str(rec["n"])] = b.get(str(rec["n"]), 0) + 1
         return hist
 
-    def summary(self, wall_s: Optional[float] = None) -> dict:
+    def summary(self, wall_s: float) -> dict:
         """The BENCH_load.json record for this run.  ``wall_s`` is the
-        trace window (last completion minus first arrival when the
-        caller tracks it; falls back to summed launch time, which
-        undercounts idle gaps)."""
+        trace window (last completion minus first arrival)."""
         lats = [r["latency_ms"] for r in self.served]
         on_time = sum(1 for r in self.served if r["on_time"])
         total = len(self.served) + len(self.shed)
-        if wall_s is None:
-            wall_s = sum(r["ms"] for r in self.launches) / 1e3
         occupied = sum(r["n"] for r in self.launches)
         padded = sum(r["bucket"] for r in self.launches)
         by_net: Dict[str, List[float]] = {}
@@ -117,9 +118,9 @@ class ServingMetrics:
             "shed_reasons": shed_reasons,
             "shed_rate": round(len(self.shed) / total, 4) if total else None,
             "goodput_rps": (round(on_time / wall_s, 3)
-                            if wall_s and wall_s > 0 else None),
+                            if wall_s > 0 else None),
             "goodput_ratio": (round(on_time / total, 4) if total else None),
-            "wall_s": round(wall_s, 4) if wall_s is not None else None,
+            "wall_s": round(wall_s, 4),
             "launches": len(self.launches),
             "mean_occupancy": (round(occupied / padded, 4)
                                if padded else None),

@@ -28,6 +28,15 @@ This module replaces that with an event loop that re-forms a batch at
   recompiles — enforced, not just hoped: every launch into an
   already-compiled ``(net, bucket, dtype)`` cell asserts the server's
   compile count did not move.
+* Each phase of a step is a span in the profiler's trace
+  (``jax.profiler.TraceAnnotation``, next to free when no profiler
+  runs): ``sched.admit`` (poll, swaps, shedding, ``take_group``,
+  admission control), ``sched.wait`` (the sleep to the next arrival),
+  ``sched.launch`` (stats ``launch``, ``net``, ``bucket``, ``n``) around
+  the server's ``serve.inputs`` and ``serve.dispatch`` and the
+  ``sched.block`` wait on the device, then ``sched.outputs`` (the launch
+  record and the per-request hand-off).  The launch record carries the
+  host ms of ``sched.outputs`` and of the server's phases.
 
 The scheduler drives any server exposing the small surface
 ``GenServer`` has (``bucket``/``max_batch``/``run_group``/``model``/
@@ -40,6 +49,9 @@ from __future__ import annotations
 
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+import jax
+from jax.profiler import TraceAnnotation
 
 from repro.launch.batching import take_group
 from repro.serving.metrics import ServingMetrics
@@ -210,6 +222,24 @@ class ContinuousScheduler:
     def step(self) -> bool:
         """One scheduling decision: launch a batch, shed, or sleep to
         the next arrival.  Returns False when fully drained."""
+        with TraceAnnotation("sched.admit"):
+            now, keep = self._admit()
+        if keep is None:
+            nxt = self.queue.next_arrival()
+            if nxt is None:
+                return False                       # drained
+            with TraceAnnotation("sched.wait"):
+                self.clock.sleep(max(0.0, nxt - now))
+            self.queue.poll(self.clock.now())
+        elif keep:
+            self._launch_group(keep[0].net, keep)
+        return True
+
+    def _admit(self):
+        """Poll arrivals, apply swaps, shed the expired, take the next
+        group and shed what admission control finds unmeetable.
+        Returns (now, the group to launch), the group None when nothing
+        is live."""
         now = self.clock.now()
         self.queue.poll(now)
         self._apply_swaps()          # launch boundary: safe swap point
@@ -224,14 +254,8 @@ class ContinuousScheduler:
             else:
                 live.append(req)
         self.queue.live = live
-
-        if not self.queue.live:
-            nxt = self.queue.next_arrival()
-            if nxt is None:
-                return False                       # drained
-            self.clock.sleep(max(0.0, nxt - now))
-            self.queue.poll(self.clock.now())
-            return True
+        if not live:
+            return now, None
 
         group, rest = take_group(self.queue.live,
                                  lambda r: r.net,
@@ -247,20 +271,17 @@ class ContinuousScheduler:
         # it) — they'd consume bucket rows to produce late output.
         est = self.estimator.estimate_ms(net,
                                          self.server.bucket(len(group)))
-        keep = group
-        if est is not None:
-            keep = []
-            for req in group:
-                if (req.deadline_t is not None
-                        and now + est / 1e3
-                        > req.deadline_t + ADMIT_SLACK * est / 1e3):
-                    self._shed(req, "unmeetable")
-                else:
-                    keep.append(req)
-        if not keep:
-            return True
-        self._launch_group(net, keep)
-        return True
+        if est is None:
+            return now, group
+        keep = []
+        for req in group:
+            if (req.deadline_t is not None
+                    and now + est / 1e3
+                    > req.deadline_t + ADMIT_SLACK * est / 1e3):
+                self._shed(req, "unmeetable")
+            else:
+                keep.append(req)
+        return now, keep
 
     def run(self) -> Dict[int, Any]:
         """Drive step() until every submitted request is served or
@@ -291,14 +312,19 @@ class ContinuousScheduler:
         fresh = cells is None or key not in cells
         count0 = getattr(self.server, "compile_count", None)
 
-        t0 = self.clock.now()
-        if self._launch_fn is not None:
-            out = self._launch_fn(net, [r.latent for r in reqs], bucket)
-        else:
-            out = self.server.run_group(net, [r.latent for r in reqs])
-            import jax
-            jax.block_until_ready(out)
-        done = self.clock.now()
+        with TraceAnnotation("sched.launch",
+                             launch=len(self.metrics.launches), net=net,
+                             bucket=bucket, n=len(reqs)):
+            t0 = self.clock.now()
+            phase_ms = {}
+            if self._launch_fn is not None:
+                out = self._launch_fn(net, [r.latent for r in reqs], bucket)
+            else:
+                out = self.server.run_group(net, [r.latent for r in reqs])
+                phase_ms = getattr(self.server, "group_ms", {})
+                with TraceAnnotation("sched.block"):
+                    jax.block_until_ready(out)
+            done = self.clock.now()
 
         if (not fresh and count0 is not None
                 and self.server.compile_count != count0):
@@ -309,23 +335,26 @@ class ContinuousScheduler:
                 "must stay closed and checkpoint swaps must reuse "
                 "executables")
 
-        self.estimator.observe(net, bucket, (done - t0) * 1e3)
-        self.metrics.record_launch(net, bucket, len(reqs),
-                                   (done - t0) * 1e3)
-        for i, req in enumerate(reqs):
-            if req.rid in self._finished:
-                raise RuntimeError(
-                    f"request {req.rid} double-served")
-            self._finished.add(req.rid)
-            req.done_t = done
-            on_time = (req.deadline_t is None or done <= req.deadline_t)
-            self.metrics.record_served(req.rid, req.net,
-                                       done - req.arrival_t, on_time)
-            if self.collect_outputs and out is not None:
-                self.results[req.rid] = out[i]
+        with TraceAnnotation("sched.outputs"):
+            t_out = time.perf_counter()
+            self.estimator.observe(net, bucket, (done - t0) * 1e3)
+            rec = self.metrics.record_launch(net, bucket, len(reqs),
+                                             (done - t0) * 1e3, **phase_ms)
+            for i, req in enumerate(reqs):
+                if req.rid in self._finished:
+                    raise RuntimeError(
+                        f"request {req.rid} double-served")
+                self._finished.add(req.rid)
+                req.done_t = done
+                on_time = (req.deadline_t is None or done <= req.deadline_t)
+                self.metrics.record_served(req.rid, req.net,
+                                           done - req.arrival_t, on_time)
+                if self.collect_outputs and out is not None:
+                    self.results[req.rid] = out[i]
+            rec["outputs_ms"] = (time.perf_counter() - t_out) * 1e3
 
     # ---- reporting -------------------------------------------------------
-    def stats(self, wall_s: Optional[float] = None) -> dict:
+    def stats(self, wall_s: float) -> dict:
         rec = self.metrics.summary(wall_s=wall_s)
         rec["swaps_applied"] = self.swaps_applied
         rec["compiles"] = getattr(self.server, "compile_count", None)

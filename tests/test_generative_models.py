@@ -60,3 +60,16 @@ def test_grad_flows_through_whole_sd_generator():
     g = jax.grad(loss)(params)
     total = sum(float(jnp.abs(x).sum()) for x in jax.tree.leaves(g))
     assert np.isfinite(total) and total > 0
+
+
+@pytest.mark.parametrize("name", ["dcgan", "mde"])
+def test_each_layer_runs_in_its_named_scope(name):
+    """The compiled forward names every layer's ops by the layer's scope
+    (``op_name`` metadata), the name a device trace groups them by."""
+    import re
+    model = build(name, "sd")
+    params = model.init(jax.random.PRNGKey(0))
+    x = jnp.zeros(model.input_shape(1))
+    text = jax.jit(model.apply).lower(params, x).compile().as_text()
+    scopes = set(re.findall(r'op_name="jit\(apply\)/([^/"]+)/', text))
+    assert {layer.name for layer in model.spec.layers} <= scopes

@@ -342,6 +342,66 @@ def test_hot_swap_zero_recompiles_and_never_mixed():
     np.testing.assert_allclose(post, ref_b_new, rtol=1e-4, atol=1e-4)
 
 
+def _host_spans(trace_dir, names):
+    """(start, end, name, stats) of each host event named in ``names`` in
+    the profile under ``trace_dir``, parents before their children."""
+    import glob
+    path = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    data = jax.profiler.ProfileData.from_file(path)
+    out = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+            dict(ev.stats))
+           for plane in data.planes for line in plane.lines
+           for ev in line.events if ev.name in names]
+    return sorted(out, key=lambda s: (s[0], -s[1]))
+
+
+STEP_SPANS = ("sched.admit", "sched.launch", "serve.inputs",
+              "serve.dispatch", "sched.block", "sched.outputs")
+
+
+def test_step_spans_split_a_launch(tmp_path):
+    """One step under the profiler: sched.launch encloses serve.inputs,
+    serve.dispatch and sched.block in that order, carries its stats, and
+    sched.outputs follows it; the launch record keeps each phase's ms."""
+    server = _server(max_batch=4)
+    server.warmup()
+    sched = ContinuousScheduler(server)
+    z = jax.random.normal(jax.random.PRNGKey(3), (3, 16))
+    for i in range(3):
+        sched.submit("g", z[i], rid=i)
+    with jax.profiler.trace(str(tmp_path)):
+        assert sched.step()
+    spans = _host_spans(str(tmp_path), STEP_SPANS + ("sched.wait",))
+    assert [s[2] for s in spans] == list(STEP_SPANS)
+    (_, admit_end, _, _), launch, *inner, outputs = spans
+    assert admit_end <= launch[0]
+    t = launch[0]
+    for a, b, name, _ in inner:
+        assert t <= a <= b <= launch[1], name
+        t = b
+    assert launch[1] <= outputs[0]
+    stats = launch[3]
+    assert (int(stats["launch"]), stats["net"], int(stats["bucket"]),
+            int(stats["n"])) == (0, "g", 4, 3)
+    rec = sched.metrics.launches[0]
+    assert {"inputs_ms", "dispatch_ms", "outputs_ms"} <= set(rec)
+    assert rec["inputs_ms"] + rec["dispatch_ms"] <= rec["ms"]
+    assert len(sched.results) == 3
+
+
+def test_wait_span_covers_the_sleep_to_the_next_arrival(tmp_path):
+    """With nothing live the step sleeps to the next arrival inside
+    sched.wait, after sched.admit, and launches nothing."""
+    sched, server, clock = _stub_sched()
+    sched.submit("n0", 0, rid=0, arrival_t=5.0)
+    with jax.profiler.trace(str(tmp_path)):
+        assert sched.step()
+    spans = _host_spans(str(tmp_path), STEP_SPANS + ("sched.wait",))
+    assert [s[2] for s in spans] == ["sched.admit", "sched.wait"]
+    assert clock.now() == 5.0 and not server.launched
+
+
 def test_server_swap_checkpoint_rebinds_engine():
     server = _server(max_batch=4)
     model, params_a = server.model("g")
