@@ -54,6 +54,7 @@ from repro.core.accounting import WORKLOADS, LayerSpec, NetworkSpec
 from repro.launch.batching import pow2_bucket, pow2_floor, take_group
 from repro.launch.mesh import make_dev_mesh
 from repro.models.generative import GenerativeModel
+from repro.serving.handoff import split_rows
 
 ALL_NETS = ("dcgan", "sngan", "artgan", "gpgan", "mde", "fst",
             "wavegan", "voxgan", "segnet")
@@ -388,7 +389,8 @@ class GenServer:
                                       self.max_batch)
             out = self.run_group(group[0].net, [r.latent for r in group])
             jax.block_until_ready(out)
-            for r, img in zip(group, out):
+            parts, _ = split_rows(out, len(group))
+            for r, img in zip(group, parts):
                 results[r.rid] = img
             groups += 1
             samples += len(group)

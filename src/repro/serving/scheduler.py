@@ -35,8 +35,11 @@ This module replaces that with an event loop that re-forms a batch at
   ``sched.launch`` (stats ``launch``, ``net``, ``bucket``, ``n``) around
   the server's ``serve.inputs`` and ``serve.dispatch`` and the
   ``sched.block`` wait on the device, then ``sched.outputs`` (the launch
-  record and the per-request hand-off).  The launch record carries the
-  host ms of ``sched.outputs`` and of the server's phases.
+  record and the per-request hand-off: one program splits the launch's
+  output into its rows, :mod:`repro.serving.handoff`).  The launch
+  record carries the host ms of ``sched.outputs`` and of the server's
+  phases, and ``handoff``: the path the hand-off took (``"split"``,
+  ``"index"``, or None when no outputs are collected).
 
 The scheduler drives any server exposing the small surface
 ``GenServer`` has (``bucket``/``max_batch``/``run_group``/``model``/
@@ -54,6 +57,7 @@ import jax
 from jax.profiler import TraceAnnotation
 
 from repro.launch.batching import take_group
+from repro.serving.handoff import INDEX, SPLIT, split_rows
 from repro.serving.metrics import ServingMetrics
 from repro.serving.queue import RequestQueue, ServeRequest
 
@@ -340,6 +344,9 @@ class ContinuousScheduler:
             self.estimator.observe(net, bucket, (done - t0) * 1e3)
             rec = self.metrics.record_launch(net, bucket, len(reqs),
                                              (done - t0) * 1e3, **phase_ms)
+            parts, rec["handoff"] = None, None
+            if self.collect_outputs and out is not None:
+                parts, rec["handoff"] = split_rows(out, len(reqs))
             for i, req in enumerate(reqs):
                 if req.rid in self._finished:
                     raise RuntimeError(
@@ -349,14 +356,17 @@ class ContinuousScheduler:
                 on_time = (req.deadline_t is None or done <= req.deadline_t)
                 self.metrics.record_served(req.rid, req.net,
                                            done - req.arrival_t, on_time)
-                if self.collect_outputs and out is not None:
-                    self.results[req.rid] = out[i]
+                if parts is not None:
+                    self.results[req.rid] = parts[i]
             rec["outputs_ms"] = (time.perf_counter() - t_out) * 1e3
 
     # ---- reporting -------------------------------------------------------
     def stats(self, wall_s: float) -> dict:
         rec = self.metrics.summary(wall_s=wall_s)
         rec["swaps_applied"] = self.swaps_applied
+        rec["handoff"] = {path: sum(r.get("handoff") == path
+                                    for r in self.metrics.launches)
+                          for path in (SPLIT, INDEX)}
         rec["compiles"] = getattr(self.server, "compile_count", None)
         cells = getattr(self.server, "_compiled", None)
         if cells is not None:

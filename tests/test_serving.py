@@ -17,6 +17,8 @@ from repro.models.generative import GenerativeModel
 from repro.serving import (ContinuousScheduler, RequestQueue,
                            ServeRequest, ServiceEstimator, ServingMetrics,
                            VirtualClock, percentile)
+from repro.serving import scheduler as scheduler_mod
+from repro.serving.handoff import handoff_rows, split_rows
 
 SPEC = reduced_spec()
 
@@ -427,6 +429,139 @@ def test_serve_async_matches_legacy_drain_outputs():
         np.testing.assert_allclose(np.asarray(results[rid]),
                                    np.asarray(legacy[rid]),
                                    rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Output hand-off: one program splits a launch's output into its rows
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("bucket,n", [(8, 8), (8, 3)])
+def test_split_rows_match_index(bucket, n):
+    """Full bucket and a cropped group: one program's rows are the
+    dtypes, shapes and bits of out[i], and stay device arrays."""
+    y = jax.random.normal(jax.random.PRNGKey(bucket + n), (bucket, 5, 4, 3))
+    for out in (y, y[:n]):          # launch output before and after crop
+        parts, path = split_rows(out, n)
+        assert path == "split" and len(parts) == n
+        for i, part in enumerate(parts):
+            assert isinstance(part, jax.Array)
+            assert part.dtype == out[i].dtype
+            assert part.shape == out[i].shape
+            np.testing.assert_array_equal(np.asarray(part),
+                                          np.asarray(out[i]))
+
+
+@pytest.mark.parametrize("kind", ["numpy", "list"])
+def test_split_rows_index_path_for_host_outputs(kind):
+    """What is not a jax.Array (a stub launch_fn's numpy array or list)
+    is indexed row by row, with the same values."""
+    y = np.arange(24, dtype=np.float32).reshape(4, 3, 2)
+    out = y if kind == "numpy" else [row.copy() for row in y]
+    parts, path = split_rows(out, 3)
+    assert path == "index" and len(parts) == 3
+    for i, part in enumerate(parts):
+        np.testing.assert_array_equal(part, y[i])
+
+
+def test_split_rows_second_launch_adds_no_compile():
+    """The split program is cached by shape and dtype: a second launch
+    of the same bucket traces and compiles nothing."""
+    events = []
+
+    def listen(event, duration, **kw):
+        if event.startswith("/jax/core/compile/"):
+            events.append(event)
+
+    y = jax.random.normal(jax.random.PRNGKey(0), (16, 6, 2))
+    jax.block_until_ready(split_rows(y, 16)[0])      # the first launch
+    size = handoff_rows._cache_size()
+    again = jax.block_until_ready(y + 1.0)           # the second's output
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        parts, path = split_rows(again, 16)
+        jax.block_until_ready(parts)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert path == "split"
+    assert handoff_rows._cache_size() == size
+    assert events == []
+
+
+_SHARDED_HANDOFF_2DEV = """
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_dev_mesh
+from repro.launch.serve_gen import GenServer, reduced_spec
+from repro.serving import ContinuousScheduler
+from repro.serving.handoff import split_rows
+assert jax.device_count() == 2
+mesh = make_dev_mesh(2, 1)
+y = jax.device_put(jnp.arange(48.0).reshape(4, 3, 4),
+                   NamedSharding(mesh, P("data")))
+parts, path = split_rows(y, 4)
+assert path == "index", path
+for i, part in enumerate(parts):
+    assert isinstance(part, jax.Array)
+    assert (np.asarray(part) == np.asarray(y[i])).all()
+server = GenServer(nets=["g"], specs={"g": reduced_spec()}, max_batch=4,
+                   dp=2)
+z = jax.random.normal(jax.random.PRNGKey(5), (4, 16))
+sched = ContinuousScheduler(server)
+for i in range(4):
+    sched.submit("g", z[i], rid=i)
+sched.run()
+ref = server.run_group("g", [z[i] for i in range(4)])
+assert len(ref.sharding.device_set) == 2
+assert [r["handoff"] for r in sched.metrics.launches] == ["index"]
+assert sched.stats(1.0)["handoff"] == {"split": 0, "index": 1}
+for i in range(4):
+    assert (np.asarray(sched.results[i]) == np.asarray(ref[i])).all()
+print("HANDOFF_OK")
+"""
+
+
+def test_split_rows_sharded_output_takes_index_path(multi_device_run):
+    """A batch-sharded output (a --dp 2 server on two devices) keeps
+    per-row indexing, with the values out[i] gives."""
+    assert "HANDOFF_OK" in multi_device_run(_SHARDED_HANDOFF_2DEV, ndev=2)
+
+
+def test_scheduler_reports_handoff_paths(monkeypatch):
+    """Launch records and stats() name the hand-off path: "split" for a
+    real server's device output, "index" for a stub's numpy output, and
+    nothing at all with collect_outputs=False."""
+    server = _server(max_batch=4)
+    sched = ContinuousScheduler(server)
+    z = jax.random.normal(jax.random.PRNGKey(4), (6, 16))
+    for i in range(6):
+        sched.submit("g", z[i], rid=i)
+    sched.run()
+    assert [r["handoff"] for r in sched.metrics.launches] == ["split"] * 2
+    assert sched.stats(1.0)["handoff"] == {"split": 2, "index": 0}
+    assert all(isinstance(sched.results[i], jax.Array) for i in range(6))
+
+    clock = VirtualClock()
+    stub = StubServer(clock, max_batch=4)
+    host = ContinuousScheduler(
+        stub, clock=clock,
+        launch_fn=lambda net, latents, bucket: np.asarray(latents) * 2.0)
+    for i in range(3):
+        host.submit("g", float(i), rid=i, arrival_t=0.0)
+    host.run()
+    assert host.stats(1.0)["handoff"] == {"split": 0, "index": 1}
+    assert [host.results[i] for i in range(3)] == [0.0, 2.0, 4.0]
+
+    def refuse(out, n):
+        raise AssertionError("hand-off ran with collect_outputs=False")
+
+    monkeypatch.setattr(scheduler_mod, "split_rows", refuse)
+    quiet = ContinuousScheduler(server, collect_outputs=False)
+    for i in range(3):
+        quiet.submit("g", z[i], rid=i)
+    quiet.run()
+    assert quiet.results == {}
+    assert [r["handoff"] for r in quiet.metrics.launches] == [None]
+    assert quiet.stats(1.0)["handoff"] == {"split": 0, "index": 0}
 
 
 # ---------------------------------------------------------------------------
