@@ -31,6 +31,10 @@ def _pair(v):
     return (v, v) if isinstance(v, int) else tuple(v)
 
 
+ACTS = ("linear", "relu", "leaky_relu")
+LEAKY_SLOPE = 0.2                  # leaky_relu's negative slope (pix2pix)
+
+
 @dataclass(frozen=True)
 class LayerSpec:
     """One layer of a benchmark network, with resolved input geometry.
@@ -39,6 +43,15 @@ class LayerSpec:
     spatial rank (1 = audio, 2 = images — the historical default — and
     3 = volumetric).  Kernels and strides stay scalar (hypercubic), as
     in every benchmarked network.
+
+    A layer computes ``norm(op(act(join(h))) * scale + b)``: ``skip``
+    names an earlier layer whose output is joined ahead of the incoming
+    channels (``[skip, h]``, so ``cin`` counts both), ``act`` is applied
+    to the (joined) input, and ``norm="instance"`` normalises each
+    request's channels over their spatial axes, with a learned
+    ``gamma``/``beta`` in place of the folded batch-norm ``scale``.
+    ``act=None`` keeps the straight-line nets' rule (see
+    :class:`NetworkSpec`): nothing on the first layer, ReLU after.
     """
     kind: str                      # 'conv' | 'deconv' | 'fc'
     cin: int
@@ -49,6 +62,21 @@ class LayerSpec:
     padding: str = "same"          # 'same' (TF semantics) or int in .pad
     pad: int = 0
     name: str = ""
+    act: Optional[str] = None      # 'linear' | 'relu' | 'leaky_relu'
+    norm: Optional[str] = None     # None | 'instance'
+    bias: bool = True
+    skip: Optional[str] = None     # layer joined ahead of the input
+
+    def __post_init__(self):
+        if self.act not in (None,) + ACTS:
+            raise ValueError(f"layer {self.name}: act {self.act!r} is not "
+                             f"one of {ACTS}")
+        if self.norm not in (None, "instance"):
+            raise ValueError(f"layer {self.name}: unknown norm "
+                             f"{self.norm!r}")
+        if self.norm is not None and self.kind == "fc":
+            raise ValueError(f"layer {self.name}: an fc layer has no "
+                             "spatial axes to normalise over")
 
     # ---- geometry -------------------------------------------------------
     @property
@@ -117,6 +145,53 @@ class NetworkSpec:
     # heads (segmentation logits) must NOT.  Carried on the spec so the
     # model factory and the serving stack can never disagree.
     final_tanh: bool = True
+
+    def __post_init__(self):
+        self.layers = [
+            l if l.act is not None
+            else replace(l, act="linear" if i == 0 else "relu")
+            for i, l in enumerate(self.layers)]
+        seen = {}
+        for i, l in enumerate(self.layers):
+            if l.skip is not None:
+                src = seen.get(l.skip)
+                prev = self.layers[i - 1] if i else None
+                if src is None or prev is None:
+                    raise ValueError(f"layer {l.name}: skip {l.skip!r} is "
+                                     "not an earlier layer")
+                if (src.out_hw() != prev.out_hw()
+                        or src.cout + prev.cout != l.cin):
+                    raise ValueError(
+                        f"layer {l.name}: joining {l.skip} "
+                        f"{src.out_hw()}x{src.cout} ahead of {prev.name} "
+                        f"{prev.out_hw()}x{prev.cout} does not give its "
+                        f"input {l.in_hw}x{l.cin}")
+            seen[l.name] = l
+
+    def skip_sources(self) -> List[str]:
+        """Names of the layers whose outputs later layers join."""
+        return [l.skip for l in self.layers if l.skip is not None]
+
+    def plain_edge(self, i: int) -> bool:
+        """True when layer ``i``'s output reaches the next layer alone
+        and unchanged: no norm here, no join ahead of the next layer,
+        not kept for a later join."""
+        layers = self.layers
+        return (i + 1 < len(layers) and layers[i].norm is None
+                and layers[i + 1].skip is None
+                and layers[i].name not in self.skip_sources())
+
+    def epilogue_act(self, i: int) -> str:
+        """The activation layer ``i`` may apply to its own output: the
+        next layer's input activation over a :meth:`plain_edge`, else
+        ``"linear"`` (the norm, join and activation run after it)."""
+        return self.layers[i + 1].act if self.plain_edge(i) else "linear"
+
+    def join_elems(self) -> int:
+        """Elements per image that the joins materialise: the joined
+        input of every layer with a ``skip``."""
+        return sum(math.prod(l.in_hw) * l.cin for l in self.layers
+                   if l.skip is not None)
 
     def deconv_layers(self) -> List[LayerSpec]:
         return [l for l in self.layers if l.kind == "deconv"]
@@ -321,8 +396,47 @@ def segnet() -> NetworkSpec:
         final_tanh=False)
 
 
+def unet(size: int, enc: Sequence[int], dec: Sequence[int],
+         name: str = "U-Net") -> NetworkSpec:
+    """pix2pix's U-Net generator (``defineG_unet`` / ``UnetGenerator``)
+    on ``size`` x ``size`` x 3 inputs: 4x4 stride-2 convs of widths
+    ``enc`` down to ``size / 2**len(enc)``, then as many 4x4 stride-2
+    deconvs of widths ``dec`` back up, each decoder layer after the
+    first joining the mirrored encoder output ahead of its input.
+    LeakyReLU(0.2) before each encoder conv but the first, ReLU before
+    each deconv; instance norm on all but the first and innermost convs
+    and the last deconv; only the last deconv has a bias, then tanh."""
+    layers, cin, hw = [], 3, size
+    for i, c in enumerate(enc, 1):
+        layers.append(LayerSpec(
+            "conv", cin, c, k=4, s=2, in_hw=(hw, hw), name=f"e{i}",
+            act="linear" if i == 1 else "leaky_relu",
+            norm="instance" if 1 < i < len(enc) else None, bias=False))
+        cin, hw = c, hw // 2
+    for k, c in enumerate(dec, 1):
+        skip = f"e{len(enc) + 1 - k}" if k > 1 else None
+        last = k == len(dec)
+        layers.append(LayerSpec(
+            "deconv", cin + (enc[-k] if skip else 0), c, k=4, s=2,
+            in_hw=(hw, hw), name=f"u{k}", act="relu",
+            norm=None if last else "instance", bias=last, skip=skip))
+        cin, hw = c, hw * 2
+    return NetworkSpec(name, layers, note="image-to-image U-Net: skip "
+                       "joins and per-request instance norm")
+
+
+def pix2pix() -> NetworkSpec:
+    """pix2pix U-Net generator (Isola et al., arXiv:1611.07004, appendix
+    6.1.1) at its published 256x256x3 and widths: eight levels down to
+    1x1.  Batch norm at test time on batch 1 is instance norm; dropout
+    is left out (eval mode).  54.4 M params; 2.02 G encoder and 4.03 G
+    decoder MACs per image, and 4x4/s2 splits with no expansion."""
+    return unet(256, (64, 128, 256, 512, 512, 512, 512, 512),
+                (512, 512, 512, 512, 256, 128, 64, 3), name="pix2pix")
+
+
 WORKLOADS = {**BENCHMARKS, "wavegan": wavegan, "voxgan": voxgan,
-             "segnet": segnet}
+             "segnet": segnet, "pix2pix": pix2pix}
 
 # Paper's published numbers, for side-by-side verification (millions).
 PAPER_TABLE1 = {  # (total, deconv)

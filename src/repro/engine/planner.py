@@ -49,6 +49,10 @@ from repro.sd.plan import (BACKENDS, DeconvPlan, plan as make_plan,
 
 Params = Dict[str, Any]
 
+# Activations the split-deconv epilogues apply (the fused kernel's,
+# and the XLA backend's after its conv).
+EPILOGUE_ACTS = ("linear", "relu")
+
 # Engine plans ARE repro.sd plans now; the old name survives for callers
 # that predate the repro.sd split (tests, benchmarks, introspection).
 LayerPlan = DeconvPlan
@@ -164,9 +168,9 @@ class SDEngine:
             if layer.kind != "deconv":
                 continue
             p = params.get(layer.name)
-            if not isinstance(p, dict) or "w" not in p or "b" not in p:
+            if not isinstance(p, dict) or "w" not in p:
                 return None
-            leaves += [p["w"], p.get("scale"), p["b"]]
+            leaves += [p["w"], p.get("scale"), p.get("b")]
         return tuple(leaves)
 
     # ---- offline phase ---------------------------------------------------
@@ -227,11 +231,13 @@ class SDEngine:
     def _chain_next(self) -> Dict[str, str]:
         """Chaining wiring from the installed calibration: maps each
         deconv layer's name to the *next* layer's name when the two are
-        consecutive in the spec, both deconv, and both calibrated — the
-        pairs whose inter-layer tensor crosses HBM as int8.  A non-last
-        engine layer always runs a fold-compatible relu epilogue; the
-        last layer has no successor, so it never chains out (its f32
-        output feeds the model-level tanh)."""
+        consecutive in the spec, both deconv, both calibrated, and the
+        first's output reaches the second alone and unchanged — no norm
+        or join between them (``NetworkSpec.plain_edge``): the pairs
+        whose inter-layer tensor crosses HBM as int8.  Their epilogue
+        activation is linear or relu, both fold-compatible; the last
+        layer has no successor, so it never chains out (its f32 output
+        feeds the model-level tanh)."""
         out: Dict[str, str] = {}
         if not self._calib or self.dtype != "int8":
             return out
@@ -240,7 +246,8 @@ class SDEngine:
             nxt = layers[i + 1]
             if (layer.kind == "deconv" and nxt.kind == "deconv"
                     and layer.name in self._calib
-                    and nxt.name in self._calib):
+                    and nxt.name in self._calib
+                    and self.spec.plain_edge(i)):
                 out[layer.name] = nxt.name
         return out
 
@@ -259,12 +266,15 @@ class SDEngine:
             if layer.kind != "deconv":
                 continue
             p = params[layer.name]
-            act = "linear" if i == len(layers) - 1 else "relu"
             shards = self._layer_shards(layer)
             tgt = chain_next.get(layer.name)
+            act = self.spec.epilogue_act(i)
+            if act not in EPILOGUE_ACTS:
+                act = "linear"              # the model applies it after
+            bias = p.get("b")
             bound = self.layer_plan(layer, act, qout=tgt is not None).bind(
                 p["w"], scale=p.get("scale"),
-                bias=p["b"].astype(jnp.float32),
+                bias=None if bias is None else bias.astype(jnp.float32),
                 mesh=self.mesh if shards > 1 else None,
                 axis=self.mp_axis)
             if (self.mesh is not None and shards == 1

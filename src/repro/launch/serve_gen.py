@@ -57,7 +57,7 @@ from repro.models.generative import GenerativeModel
 from repro.serving.handoff import split_rows
 
 ALL_NETS = ("dcgan", "sngan", "artgan", "gpgan", "mde", "fst",
-            "wavegan", "voxgan", "segnet")
+            "wavegan", "voxgan", "segnet", "pix2pix")
 
 
 @dataclass
@@ -152,6 +152,7 @@ class GenServer:
         self._compiled: Dict[Tuple, Any] = {}
         self.compile_count = 0          # incremented at trace time
         self.group_ms: Dict[str, float] = {}   # last run_group's phases
+        self.group_join_bytes = 0       # and the bytes its joins wrote
         self._mesh = None
         if self.dp > 1 or self.mp > 1:
             need = self.dp * self.mp
@@ -189,13 +190,15 @@ class GenServer:
         return self._models[net]
 
     def _serving_args(self, net: str, bucket: int):
-        """(non-deconv params, bound plans) for the compiled call.  The
-        deconv weights live pre-split inside the plans — shipping the
-        raw filters too would feed the executable dead operands (and
-        replicate them across the dp mesh).  Plans carry tiles resolved
-        for *this bucket's batch* (``engine.plans_for_batch``), so a
-        ``plan_batch=1`` bind no longer leaks its tiny-batch tiles into
-        batch-16 launches.  Cached per (net, bucket), keyed on the live
+        """(params the plans do not hold, bound plans) for the compiled
+        call.  The deconv weights, scales and biases live pre-split
+        inside the plans — shipping the raw filters too would feed the
+        executable dead operands (and replicate them across the dp
+        mesh); a deconv layer's norm ``gamma``/``beta`` still go.
+        Plans carry tiles resolved for *this bucket's batch*
+        (``engine.plans_for_batch``), so a ``plan_batch=1`` bind no
+        longer leaks its tiny-batch tiles into batch-16 launches.
+        Cached per (net, bucket), keyed on the live
         params object, so the serving loop does no per-group dict
         rebuilding; a rebind (new params) invalidates."""
         model, params = self.model(net)
@@ -203,7 +206,10 @@ class GenServer:
         cached = self._serving.get(key)
         if cached is None or cached[0] is not params:
             deconv = {l.name for l in model.spec.deconv_layers()}
-            lean = {k: v for k, v in params.items() if k not in deconv}
+            lean = {name: ({k: v for k, v in p.items()
+                            if k not in ("w", "scale", "b")}
+                           if name in deconv else p)
+                    for name, p in params.items()}
             self._serving[key] = (params, lean,
                                   model.engine.plans_for_batch(bucket))
         _, lean, plans = self._serving[key]
@@ -289,6 +295,12 @@ class GenServer:
             return None
         return model.engine.estimate_ms(bucket)
 
+    def join_bytes(self, net: str, bucket: int) -> int:
+        """Bytes one launch of the cell writes by concatenating skip
+        outputs ahead of decoder inputs (``NetworkSpec.join_elems``):
+        the traffic a kernel reading two operands would not make."""
+        return bucket * self._specs[net].join_elems() * self.dtype.itemsize
+
     def compiled(self, net: str, bucket: int):
         """The jitted padded-batch executable for one cell (see
         :meth:`cell_key`).
@@ -354,7 +366,8 @@ class GenServer:
         ``group_ms`` for the scheduler's launch record: ``serve.inputs``
         (``inputs_ms``: per-request copies, stack, pad) and
         ``serve.dispatch`` (``dispatch_ms``: the compiled call and the
-        crop, enqueued, not waited on)."""
+        crop, enqueued, not waited on); ``group_join_bytes`` is the
+        launch's :meth:`join_bytes`."""
         n = len(latents)
         bucket = self.bucket(n)
         lean_params, plans = self._serving_args(net, bucket)
@@ -369,6 +382,7 @@ class GenServer:
             y = self.compiled(net, bucket)(lean_params, plans, x)[:n]
         self.group_ms = {"inputs_ms": (t1 - t0) * 1e3,
                          "dispatch_ms": (time.perf_counter() - t1) * 1e3}
+        self.group_join_bytes = self.join_bytes(net, bucket)
         return y
 
     def serve(self, requests: List[GenRequest]):

@@ -19,6 +19,9 @@ backend, with no splitting on the hot path either way.
 
 Inference-time batch norm is folded into per-channel scale/bias (gamma,
 beta) as any deployment on the paper's target processors would do.
+Layers may also join an earlier layer's output ahead of their input and
+take an instance norm (the pix2pix U-Net, ``LayerSpec``); both run in
+XLA around the deconv, whose plan then leaves its epilogue linear.
 """
 
 from __future__ import annotations
@@ -31,7 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import conv_nd, registry, same_deconv_pads
-from repro.core.accounting import BENCHMARKS, WORKLOADS, NetworkSpec
+from repro.core.accounting import (BENCHMARKS, LEAKY_SLOPE, WORKLOADS,
+                                   NetworkSpec)
 from repro import sd
 
 Params = Dict[str, Any]
@@ -76,25 +80,29 @@ class GenerativeModel:
 
     # ---- params ----------------------------------------------------------
     def init(self, key: jax.Array, dtype=jnp.float32) -> Params:
+        """Normal weights over sqrt(fan-in); a bias only where the spec
+        has one, the folded batch-norm ``scale`` on conv/deconv layers
+        without a norm, and ``gamma``/``beta`` on instance-norm layers."""
         params: Params = {}
         keys = jax.random.split(key, len(self.spec.layers))
         for k, layer in zip(keys, self.spec.layers):
+            c = layer.cout
             if layer.kind == "fc":
-                fan_in = layer.cin
-                w = jax.random.normal(k, (layer.cin, layer.cout), dtype)
-                params[layer.name] = {
-                    "w": w / math.sqrt(fan_in),
-                    "b": jnp.zeros((layer.cout,), dtype)}
+                w = jax.random.normal(k, (layer.cin, c), dtype)
+                p = {"w": w / math.sqrt(layer.cin)}
             else:
                 fan_in = layer.k ** layer.rank * layer.cin
                 w = jax.random.normal(
-                    k, (*(layer.k,) * layer.rank, layer.cin, layer.cout),
-                    dtype)
-                params[layer.name] = {
-                    "w": w / math.sqrt(fan_in),
-                    "b": jnp.zeros((layer.cout,), dtype),
-                    "scale": jnp.ones((layer.cout,), dtype),  # folded BN
-                }
+                    k, (*(layer.k,) * layer.rank, layer.cin, c), dtype)
+                p = {"w": w / math.sqrt(fan_in)}
+                if layer.norm is None:
+                    p["scale"] = jnp.ones((c,), dtype)   # folded BN
+                else:
+                    p["gamma"] = jnp.ones((c,), dtype)
+                    p["beta"] = jnp.zeros((c,), dtype)
+            if layer.bias:
+                p["b"] = jnp.zeros((c,), dtype)
+            params[layer.name] = p
         if self._engine is not None:
             # Offline phase: split + BN-fold every deconv filter exactly
             # once, here at init.  apply() never touches split_filters.
@@ -134,45 +142,59 @@ class GenerativeModel:
     def _forward(self, params: Params, x: jax.Array,
                  deconv_step) -> jax.Array:
         """The one shared layer loop.  ``deconv_step(layer, p, h) ->
-        (h, epilogue_done)`` supplies the deconv strategy; everything
-        else (fc matmul + reshape, conv + BN, inter-layer ReLU, final
+        (h, act)`` supplies the deconv strategy: ``act`` is None when it
+        returns the bare deconv, else the activation its epilogue applied
+        after the folded scale and bias.  Everything else (joins, input
+        activations, fc matmul + reshape, conv + BN, instance norm, final
         tanh) lives here exactly once, so every execution path — plain
         impls, cached engine plans, traced-params functional, serving
         plans-as-arguments — shares identical non-deconv semantics."""
         layers = self.spec.layers
-        h = x
+        keep = set(self.spec.skip_sources())
+        kept: Dict[str, jax.Array] = {}
+        h, done = x, None       # done: the act an epilogue applied to h
         for i, layer in enumerate(layers):
             # One scope per layer names its device ops in a profile.
             with jax.named_scope(layer.name):
-                p = params.get(layer.name)   # deconv steps may not need it
-                last = i == len(layers) - 1
+                p = params.get(layer.name, {})   # deconv steps may not
+                if layer.skip is not None:       # need it
+                    h = jnp.concatenate([kept[layer.skip], h], axis=-1)
+                # The previous plan's epilogue applied this act only over
+                # a plain edge (no join here): NetworkSpec.epilogue_act.
+                if layer.act != done:
+                    h = activate(h, layer.act)
+                done = None
                 if layer.kind == "fc":
                     h = h.reshape(h.shape[0], -1)
-                    h = jnp.matmul(
-                        h, p["w"],
-                        precision=jax.lax.Precision.HIGHEST) + p["b"]
+                    h = jnp.matmul(h, p["w"],
+                                   precision=jax.lax.Precision.HIGHEST)
+                    h = _scale_bias(h, p)
                     # reshape for the next spatial layer (any rank)
                     nxt = layers[i + 1] if i + 1 < len(layers) else None
                     if nxt is not None and nxt.kind != "fc":
                         h = h.reshape(h.shape[0], *nxt.in_hw, nxt.cin)
                 elif layer.kind == "conv":
                     pads = "SAME" if layer.padding == "same" else layer.pad
-                    h = conv_nd(h, p["w"], layer.s, pads)
-                    h = h * p["scale"] + p["b"]
+                    h = _scale_bias(conv_nd(h, p["w"], layer.s, pads), p)
                 else:                        # deconv: strategy-dependent
-                    h, epilogue_done = deconv_step(layer, p, h)
-                    if epilogue_done:
-                        continue
-                if not last:
-                    h = jax.nn.relu(h)
+                    h, done = deconv_step(layer, p, h)
+                    if done is None:
+                        h = _scale_bias(h, p)
+                if layer.norm == "instance":
+                    h = instance_norm(h, p["gamma"], p["beta"])
+                if layer.name in keep:
+                    kept[layer.name] = h
         return jnp.tanh(h) if self.final_tanh else h
 
     def apply(self, params: Params, x: jax.Array) -> jax.Array:
         if self._engine_ready(params):
             # scale is folded into the cached split filters; bias and
-            # the inter-layer ReLU run in the kernel/plan epilogue.
+            # the plan's activation run in the kernel/plan epilogue.
+            plans = self._engine.plans()
+
             def step(layer, p, h):
-                return self._engine.run(layer.name, h), True
+                return (self._engine.run(layer.name, h),
+                        plans[layer.name].act)
         elif self._engine is not None:   # traced params: differentiable
             def step(layer, p, h):
                 fp = self._functional_plan(layer)
@@ -185,15 +207,13 @@ class GenerativeModel:
                     n, ax = scope
                     if n > 1 and layer.cout % n == 0:
                         fp = fp.with_shards(n, ax)
-                h = sd.conv_transpose(fp, h, p["w"])
-                return h * p["scale"] + p["b"], False
+                return sd.conv_transpose(fp, h, p["w"]), None
         else:                            # plain registry executor
             def step(layer, p, h):
                 pads = (same_deconv_pads((layer.k,) * layer.rank,
                                          (layer.s,) * layer.rank)
                         if layer.padding == "same" else layer.pad)
-                h = self._deconv(h, p["w"], layer.s, pads)
-                return h * p["scale"] + p["b"], False
+                return self._deconv(h, p["w"], layer.s, pads), None
         return self._forward(params, x, step)
 
     def apply_with_plans(self, params: Params,
@@ -204,10 +224,13 @@ class GenerativeModel:
         state.  Pure in all three arguments — params AND plans are
         pytrees, so the serving stack jits this once per shape and
         swaps weights/plans per call without recompiling.  ``params``
-        only needs the fc/conv entries (deconv weights live pre-split
-        inside the plans — the server passes the filtered dict)."""
+        only needs what the plans do not hold: the fc/conv entries and
+        the deconv layers' norm parameters (deconv weights, scale and
+        bias live pre-split inside the plans — the server passes the
+        filtered dict)."""
         def step(layer, p, h):           # bias + act in the bound plan
-            return sd.execute(plans[layer.name], h), True
+            plan = plans[layer.name]
+            return sd.execute(plan, h), plan.act
 
         return self._forward(params, x, step)
 
@@ -263,8 +286,7 @@ class GenerativeModel:
             # model serves) so downstream layers see faithful inputs.
             stats[layer.name] = amax_stat(h, policy, pct)
             fp = self._functional_plan(layer)
-            h = sd.conv_transpose(fp, h, p["w"])
-            return h * p["scale"] + p["b"], False
+            return sd.conv_transpose(fp, h, p["w"]), None
 
         self._forward(params, x, step)
         scales = {name: scale_from_amax(v) for name, v in stats.items()}
@@ -310,6 +332,39 @@ def build(name: str, deconv_impl: str = "sd",
     return GenerativeModel(WORKLOADS[name](), deconv_impl=deconv_impl,
                            engine_backend=engine_backend,
                            engine_dtype=engine_dtype)
+
+
+def activate(h: jax.Array, act: str) -> jax.Array:
+    """A layer's input activation (``LayerSpec.act``)."""
+    if act == "relu":
+        return jax.nn.relu(h)
+    if act == "leaky_relu":
+        return jax.nn.leaky_relu(h, LEAKY_SLOPE)
+    return h
+
+
+def instance_norm(h: jax.Array, gamma: jax.Array, beta: jax.Array,
+                  eps: float = 1e-5) -> jax.Array:
+    """Normalise each request's channels over its spatial axes, in
+    float32: statistics never cross the batch, so a bucket's other
+    requests and padding rows cannot move a request's output."""
+    axes = tuple(range(1, h.ndim - 1))
+    z = h.astype(jnp.float32)
+    mean = jnp.mean(z, axis=axes, keepdims=True)
+    var = jnp.mean(jnp.square(z - mean), axis=axes, keepdims=True)
+    z = (z - mean) * jax.lax.rsqrt(var + eps)
+    return (z * gamma.astype(jnp.float32)
+            + beta.astype(jnp.float32)).astype(h.dtype)
+
+
+def _scale_bias(h: jax.Array, p: Params) -> jax.Array:
+    """The folded batch-norm scale and the bias, where the layer has
+    them."""
+    if "scale" in p:
+        h = h * p["scale"]
+    if "b" in p:
+        h = h + p["b"]
+    return h
 
 
 # --------------------------------------------------------------------------

@@ -38,8 +38,10 @@ This module replaces that with an event loop that re-forms a batch at
   record and the per-request hand-off: one program splits the launch's
   output into its rows, :mod:`repro.serving.handoff`).  The launch
   record carries the host ms of ``sched.outputs`` and of the server's
-  phases, and ``handoff``: the path the hand-off took (``"split"``,
-  ``"index"``, or None when no outputs are collected).
+  phases, ``join_bytes`` (what the server's skip joins wrote, from
+  ``GenServer.group_join_bytes``) and ``handoff``: the path the
+  hand-off took (``"split"``, ``"index"``, or None when no outputs are
+  collected).
 
 The scheduler drives any server exposing the small surface
 ``GenServer`` has (``bucket``/``max_batch``/``run_group``/``model``/
@@ -320,12 +322,13 @@ class ContinuousScheduler:
                              launch=len(self.metrics.launches), net=net,
                              bucket=bucket, n=len(reqs)):
             t0 = self.clock.now()
-            phase_ms = {}
+            phase_ms, join_bytes = {}, 0
             if self._launch_fn is not None:
                 out = self._launch_fn(net, [r.latent for r in reqs], bucket)
             else:
                 out = self.server.run_group(net, [r.latent for r in reqs])
                 phase_ms = getattr(self.server, "group_ms", {})
+                join_bytes = getattr(self.server, "group_join_bytes", 0)
                 with TraceAnnotation("sched.block"):
                     jax.block_until_ready(out)
             done = self.clock.now()
@@ -344,6 +347,7 @@ class ContinuousScheduler:
             self.estimator.observe(net, bucket, (done - t0) * 1e3)
             rec = self.metrics.record_launch(net, bucket, len(reqs),
                                              (done - t0) * 1e3, **phase_ms)
+            rec["join_bytes"] = join_bytes
             parts, rec["handoff"] = None, None
             if self.collect_outputs and out is not None:
                 parts, rec["handoff"] = split_rows(out, len(reqs))
@@ -367,6 +371,8 @@ class ContinuousScheduler:
         rec["handoff"] = {path: sum(r.get("handoff") == path
                                     for r in self.metrics.launches)
                           for path in (SPLIT, INDEX)}
+        rec["join_bytes"] = sum(r["join_bytes"]
+                                for r in self.metrics.launches)
         rec["compiles"] = getattr(self.server, "compile_count", None)
         cells = getattr(self.server, "_compiled", None)
         if cells is not None:

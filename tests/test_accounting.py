@@ -48,3 +48,15 @@ def test_sd_expansion_ratios():
                      in_hw=(4, 4)).sd_expansion() == pytest.approx(16 / 9)
     assert LayerSpec("deconv", 4, 4, k=5, s=1,
                      in_hw=(4, 4)).sd_expansion() == 1.0
+
+
+def test_pix2pix_params_and_macs():
+    """The U-Net at its published widths (arXiv:1611.07004, 6.1.1):
+    54.4 M weights, 2.02 G encoder + 4.03 G decoder MACs per image."""
+    from repro.core.accounting import WORKLOADS
+    net = WORKLOADS["pix2pix"]()
+    assert sum(l.params() for l in net.layers) / M == pytest.approx(54.4,
+                                                                    abs=0.05)
+    assert net.total_macs() / 1e9 == pytest.approx(6.05, abs=0.005)
+    assert net.deconv_macs() / 1e9 == pytest.approx(4.03, abs=0.005)
+    assert net.deconv_sd_macs() == net.deconv_macs()    # k=4, s=2

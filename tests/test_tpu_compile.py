@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.accounting import dcgan, mde
+from repro.core.accounting import dcgan, mde, pix2pix
 from repro.core.deconv import (_pads, deconv_output_shape,
                                same_deconv_pads, sd_geometry)
 from repro.kernels import autotune, sd_conv, winograd
@@ -28,10 +28,14 @@ from repro.kernels.autotune import ConvGeom
 
 _DCGAN = {l.name: l for l in dcgan().deconv_layers()}
 _MDE = {l.name: l for l in mde().deconv_layers()}
-# (layer, batch): the DCGAN serving buckets' extremes and MDE's widest
-# decoder layer, whose 512-wide output takes the width-tiling path.
+_UNET = [l for l in pix2pix().deconv_layers()]
+# (layer, batch): the DCGAN serving buckets' extremes, MDE's widest
+# decoder layer, whose 512-wide output takes the width-tiling path, and
+# every pix2pix U-Net deconv at the cell's bucket 32 (1x1 and 2x2
+# inputs, Cin up to 1024).
 LAYERS = {"dcgan_d1": (_DCGAN["d1"], 16), "dcgan_d2": (_DCGAN["d2"], 16),
-          "dcgan_d3": (_DCGAN["d3"], 16), "mde_up1": (_MDE["up1"], 1)}
+          "dcgan_d3": (_DCGAN["d3"], 16), "mde_up1": (_MDE["up1"], 1),
+          **{f"pix2pix_{l.name}": (l, 32) for l in _UNET}}
 
 
 @pytest.fixture(scope="module")
@@ -91,6 +95,27 @@ def test_fused_float_compiles(one_chip, name, dtype):
         lambda x, ws, bias: sd_conv.sd_fused_pallas(
             x, ws, s, bias=bias, act="relu", interpret=False, **kw),
         x, ws, bias)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("name", [f"pix2pix_{l.name}" for l in _UNET])
+def test_unet_deconvs_compile(one_chip, name):
+    """The U-Net's launches as the engine makes them: a linear epilogue
+    (instance norm follows, or tanh after u8) and a bias on u8 alone."""
+    layer, b, kt, s, kw = _launch(name)
+    x = jax.ShapeDtypeStruct((b, *layer.in_hw, layer.cin), jnp.float32,
+                             sharding=one_chip)
+    ws = jax.ShapeDtypeStruct((kt, kt, layer.cin, layer.cout * s * s),
+                              jnp.float32, sharding=one_chip)
+    args = [x, ws]
+    if layer.bias:
+        args.append(jax.ShapeDtypeStruct((layer.cout,), jnp.float32,
+                                         sharding=one_chip))
+    text = _compile_text(
+        lambda x, ws, *bias: sd_conv.sd_fused_pallas(
+            x, ws, s, bias=bias[0] if bias else None, act="linear",
+            interpret=False, **kw),
+        *args)
     assert "tpu_custom_call" in text
 
 
