@@ -54,7 +54,7 @@ from repro.core.accounting import WORKLOADS, LayerSpec, NetworkSpec
 from repro.launch.batching import pow2_bucket, pow2_floor, take_group
 from repro.launch.mesh import make_dev_mesh
 from repro.models.generative import GenerativeModel
-from repro.serving.handoff import split_rows
+from repro.serving.handoff import split_rows, stack_rows
 
 ALL_NETS = ("dcgan", "sngan", "artgan", "gpgan", "mde", "fst",
             "wavegan", "voxgan", "segnet", "pix2pix")
@@ -364,19 +364,18 @@ class GenServer:
 
         Its two phases are profiler spans, and their host ms are left in
         ``group_ms`` for the scheduler's launch record: ``serve.inputs``
-        (``inputs_ms``: per-request copies, stack, pad) and
-        ``serve.dispatch`` (``dispatch_ms``: the compiled call and the
-        crop, enqueued, not waited on); ``group_join_bytes`` is the
-        launch's :meth:`join_bytes`."""
+        (``inputs_ms``: the padded batch from
+        :func:`repro.serving.handoff.stack_rows`, stacked on the host and
+        copied to the device in one call) and ``serve.dispatch``
+        (``dispatch_ms``: the compiled call and the crop, enqueued, not
+        waited on); ``group_join_bytes`` is the launch's
+        :meth:`join_bytes`."""
         n = len(latents)
         bucket = self.bucket(n)
         lean_params, plans = self._serving_args(net, bucket)
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("serve.inputs"):
-            x = jnp.stack([jnp.asarray(z, self.dtype) for z in latents])
-            if bucket > n:
-                pad = jnp.zeros((bucket - n, *x.shape[1:]), self.dtype)
-                x = jnp.concatenate([x, pad])
+            x = stack_rows(latents, bucket, self.dtype)
         t1 = time.perf_counter()
         with jax.profiler.TraceAnnotation("serve.dispatch"):
             y = self.compiled(net, bucket)(lean_params, plans, x)[:n]
@@ -420,7 +419,9 @@ class GenServer:
                         ) -> List[GenRequest]:
         model, _ = self.model(net)
         shape = model.input_shape(n)
-        z = jax.random.normal(jax.random.PRNGKey(seed), shape, self.dtype)
+        # Host rows: run_group stacks a launch's rows on the host.
+        z = np.asarray(jax.random.normal(jax.random.PRNGKey(seed), shape,
+                                         self.dtype))
         return [GenRequest(rid=i, net=net, latent=z[i]) for i in range(n)]
 
 

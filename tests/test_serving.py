@@ -17,8 +17,9 @@ from repro.models.generative import GenerativeModel
 from repro.serving import (ContinuousScheduler, RequestQueue,
                            ServeRequest, ServiceEstimator, ServingMetrics,
                            VirtualClock, percentile)
+from repro.serving import handoff
 from repro.serving import scheduler as scheduler_mod
-from repro.serving.handoff import handoff_rows, split_rows
+from repro.serving.handoff import handoff_rows, split_rows, stack_rows
 
 SPEC = reduced_spec()
 
@@ -429,6 +430,185 @@ def test_serve_async_matches_legacy_drain_outputs():
         np.testing.assert_allclose(np.asarray(results[rid]),
                                    np.asarray(legacy[rid]),
                                    rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Input hand-off: host rows stacked on the host, one transfer per launch
+# ---------------------------------------------------------------------------
+
+def _device_stack(latents, bucket, dtype):
+    """The batch as run_group built it before stack_rows: per-row
+    jnp.asarray, a device stack and a zero pad."""
+    x = jnp.stack([jnp.asarray(z, dtype) for z in latents])
+    if bucket > len(latents):
+        pad = jnp.zeros((bucket - len(latents), *x.shape[1:]), dtype)
+        x = jnp.concatenate([x, pad])
+    return x
+
+
+def _rows(n, seed=0):
+    """``n`` float32 host rows; the first holds bfloat16 rounding ties
+    (low 16 bits 0x8000, odd and even above them) and signed zeros."""
+    rng = np.random.RandomState(seed)
+    rows = [rng.standard_normal((5, 4, 3)).astype(np.float32)
+            for _ in range(n)]
+    high = (rng.randint(0x3C00, 0x4400, size=rows[0].size, dtype=np.uint32)
+            | rng.randint(0, 2, size=rows[0].size, dtype=np.uint32) << 15)
+    ties = ((high << 16) | 0x8000).view(np.float32).reshape(rows[0].shape)
+    ties[0, 0, :2] = (0.0, -0.0)
+    rows[0] = ties
+    return rows
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint8)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("bucket,n", [(8, 8), (8, 3)])
+def test_stack_rows_host_path_matches_device_stack(dtype, bucket, n):
+    """Host rows take one host stack and one transfer; the batch is the
+    old device stack/pad bit for bit (the host bfloat16 cast rounds to
+    nearest even, as the device convert does), pad rows exact zeros,
+    placed uncommitted on the default device as the old batch was."""
+    dtype = jnp.dtype(dtype)
+    rows = _rows(n)
+    x = stack_rows(rows, bucket, dtype)
+    assert isinstance(x, jax.Array) and not x.committed
+    assert x.devices() == {jax.devices()[0]}
+    assert x.dtype == dtype and x.shape == (bucket, 5, 4, 3)
+    old = _device_stack(rows, bucket, dtype)
+    np.testing.assert_array_equal(_bits(x), _bits(old))
+    converted = jnp.stack([jnp.asarray(z).astype(dtype) for z in rows])
+    np.testing.assert_array_equal(_bits(x[:n]), _bits(converted))
+    assert not np.asarray(x[n:], np.float32).any()
+    assert not _bits(x[n:]).any()                   # +0.0, not -0.0
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("bucket,n", [(8, 8), (8, 3)])
+def test_stack_rows_chunked_copy_matches_device_stack(dtype, bucket, n,
+                                                      monkeypatch):
+    """A batch over CHUNK_BYTES goes over in row blocks joined on the
+    device; the batch is still the old device stack/pad bit for bit,
+    one uncommitted array on the default device."""
+    dtype = jnp.dtype(dtype)
+    rows = _rows(n)
+    row_bytes = rows[0].size * dtype.itemsize
+    monkeypatch.setattr(handoff, "CHUNK_BYTES", 3 * row_bytes)
+    x = stack_rows(rows, bucket, dtype)
+    assert isinstance(x, jax.Array) and not x.committed
+    assert x.devices() == {jax.devices()[0]}
+    assert x.dtype == dtype and x.shape == (bucket, 5, 4, 3)
+    np.testing.assert_array_equal(_bits(x),
+                                  _bits(_device_stack(rows, bucket, dtype)))
+    assert not _bits(x[n:]).any()
+
+
+@pytest.mark.parametrize("kind", ["device", "mixed"])
+def test_stack_rows_device_rows_match_device_stack(kind):
+    """Rows that are jax.Arrays (rows of a device batch, alone or mixed
+    with host rows) are read back and stacked on the host, and give the
+    old device stack/pad's values bit for bit."""
+    z = jax.random.normal(jax.random.PRNGKey(3), (3, 5, 4, 3))
+    rows = [z[i] for i in range(3)]
+    if kind == "mixed":
+        rows[1] = np.asarray(rows[1])
+    for dtype in (jnp.dtype("float32"), jnp.dtype("bfloat16")):
+        x = stack_rows(rows, 4, dtype)
+        assert x.dtype == dtype and x.shape == (4, 5, 4, 3)
+        np.testing.assert_array_equal(_bits(x),
+                                      _bits(_device_stack(rows, 4, dtype)))
+
+
+@pytest.mark.parametrize("path", ["host", "device", "chunked"])
+def test_run_group_repeat_launch_adds_no_compile(path, monkeypatch):
+    """A second launch of one shape, from host rows, from device rows
+    or copied in row blocks, retraces no cell and compiles no program,
+    and gives the first's outputs."""
+    if path == "chunked":
+        monkeypatch.setattr(handoff, "CHUNK_BYTES", 64)
+    server = _server(max_batch=4)
+    z = jax.random.normal(jax.random.PRNGKey(8), (3, 16))
+    rows = ([z[i] for i in range(3)] if path == "device"
+            else [np.asarray(z[i]) for i in range(3)])
+    first = jax.block_until_ready(server.run_group("g", rows))
+    count = server.compile_count
+    events = []
+
+    def listen(event, duration, **kw):
+        if event.startswith("/jax/core/compile/"):
+            events.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        again = jax.block_until_ready(server.run_group("g", rows))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert server.compile_count == count
+    assert events == []
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(first))
+
+
+def test_scheduler_serves_host_and_device_latents_alike():
+    """The scheduler serves numpy latents and jax.Array latents of the
+    same values to the same outputs, one launch each."""
+    server = _server(max_batch=4)
+    z = jax.random.normal(jax.random.PRNGKey(6), (4, 16))
+    sched = ContinuousScheduler(server)
+    for i in range(4):
+        sched.submit("g", np.asarray(z[i]), rid=i)
+    sched.run()
+    for i in range(4):
+        sched.submit("g", z[i], rid=10 + i)
+    sched.run()
+    assert [r["n"] for r in sched.metrics.launches] == [4, 4]
+    assert sched.stats(1.0)["handoff"] == {"split": 2, "index": 0}
+    for i in range(4):
+        np.testing.assert_array_equal(np.asarray(sched.results[i]),
+                                      np.asarray(sched.results[10 + i]))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_random_requests_draws_host_rows(dtype):
+    """random_requests hands out host rows, so run_group never reads a
+    row back from the device, holding the draw it always made."""
+    server = _server(max_batch=4, dtype=dtype)
+    reqs = server.random_requests("g", 3, seed=4)
+    want = jax.random.normal(jax.random.PRNGKey(4), (3, 16),
+                             jnp.dtype(dtype))
+    for i, r in enumerate(reqs):
+        assert isinstance(r.latent, np.ndarray)
+        assert r.latent.dtype == jnp.dtype(dtype)
+        np.testing.assert_array_equal(_bits(r.latent), _bits(want[i]))
+
+
+_HOST_INPUTS_2DEV = """
+import numpy as np, jax
+from repro.launch.serve_gen import GenServer, reduced_spec
+from repro.serving import ContinuousScheduler
+assert jax.device_count() == 2
+server = GenServer(nets=["g"], specs={"g": reduced_spec()}, max_batch=4,
+                   dp=2)
+z = jax.random.normal(jax.random.PRNGKey(9), (3, 16))
+ref = server.run_group("g", [z[i] for i in range(3)])
+count = server.compile_count
+sched = ContinuousScheduler(server)
+for i in range(3):
+    sched.submit("g", np.asarray(z[i]), rid=i)
+sched.run()
+assert len(sched.metrics.launches) == 1
+assert server.compile_count == count
+for i in range(3):
+    assert (np.asarray(sched.results[i]) == np.asarray(ref[i])).all()
+print("HOST_INPUTS_OK")
+"""
+
+
+def test_stack_rows_host_inputs_on_dp2_mesh(multi_device_run):
+    """A --dp 2 server on two devices, fed numpy latents, runs the same
+    compiled cell and matches its device-latent result."""
+    assert "HOST_INPUTS_OK" in multi_device_run(_HOST_INPUTS_2DEV, ndev=2)
 
 
 # ---------------------------------------------------------------------------
