@@ -435,8 +435,7 @@ def sd_deconv_paper(x: jax.Array, w: jax.Array, stride: IntPair,
 
     Numerically identical to :func:`sd_deconv`; on TPU the grouped
     single-conv formulation (sd_deconv) reuses each input tile for all
-    s^2 sub-filters in one GEMM — the beyond-paper optimisation measured
-    in benchmarks/sd_roofline.py.
+    s^2 sub-filters in one GEMM — the beyond-paper optimisation.
     """
     sh, sw = _pair(stride)
     (pt, pb), (pl, pr) = _pads(padding)

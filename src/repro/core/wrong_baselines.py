@@ -1,8 +1,8 @@
 """Prior deconv-to-conv conversions the paper compares against (Table 4).
 
 Both are *incorrect* in general — that is the paper's point — and both are
-reproduced here so the SSIM comparison (benchmarks/table4_ssim.py) can
-quantify the damage:
+reproduced here so an SSIM comparison against the exact deconvolution
+can quantify the damage:
 
 * ``shi_deconv``   — Shi et al. [30] ("Is the deconvolution layer the same
   as a convolutional layer?"): sub-pixel conversion with a *fixed*

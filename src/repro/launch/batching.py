@@ -1,20 +1,18 @@
-"""Request bucketing shared by the serving stacks.
-
-Two servers use these helpers:
+"""Request grouping and batch buckets.
 
 * :mod:`repro.launch.serve` (LM) groups queued prompts into decode
   slots.  Grouping must be by *equal prompt length* — the seed's
   ``plen = min(...)`` truncated longer prompts in a mixed group,
   silently changing what the model was asked to continue.
-* :mod:`repro.launch.serve_gen` (generative) groups requests by
-  (arch, dtype) and pads the group to a batch *bucket* so the jit
-  compile cache sees a small closed set of shapes instead of one entry
-  per request count.
+* :mod:`repro.serving.scheduler` groups generative requests by net at
+  every launch boundary, and :mod:`repro.launch.serve_gen` pads each
+  group to a batch *bucket* so the jit compile cache sees a small
+  closed set of shapes instead of one entry per request count.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional, Sequence, Tuple, TypeVar
+from typing import Callable, Dict, List, Optional, Tuple, TypeVar
 
 T = TypeVar("T")
 
@@ -105,15 +103,3 @@ def take_group(queue: List[T], key_fn: Callable[[T], object],
             rest.append(item)
     return group, rest
 
-
-def drain_groups(queue: Sequence[T], key_fn: Callable[[T], object],
-                 max_group: int) -> List[List[T]]:
-    """Split a whole queue into compatible FIFO groups (for batch-mode
-    serving and tests; the live loop calls :func:`take_group` per
-    refill boundary)."""
-    q = list(queue)
-    groups: List[List[T]] = []
-    while q:
-        group, q = take_group(q, key_fn, max_group)
-        groups.append(group)
-    return groups

@@ -2,8 +2,7 @@
 
 Compiling the Pallas kernels and the serving cells is a large part of a
 cold run, so the entry points (``chip_smoke.py``, ``serve_gen``,
-``benchmarks/run.py``, ``examples/train_dcgan.py``) keep compiled
-programs across processes.  Library code and tests never enable it.
+``examples/train_dcgan.py``) keep compiled programs across processes.  Library code and tests never enable it.
 """
 
 from __future__ import annotations

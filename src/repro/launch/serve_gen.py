@@ -26,12 +26,11 @@ single-sample generator calls waste the accelerator, so the server
   (net, bucket, layer) geometry at server start — bind-time
   ``plan_batch=1`` tiles no longer leak into batch-16 launches.
 
-Two serving loops share this machinery: the **async continuous-batching
-scheduler** (:mod:`repro.serving`, the default — re-forms a bucket at
-every launch boundary, honours ``--deadline-ms`` with admission
-control, supports live checkpoint hot-swap) and the **legacy drain
-loop** (:meth:`GenServer.serve`, ``--sched drain`` — kept as the
-closed-loop baseline ``benchmarks/loadgen.py`` measures against).
+Requests are served by the async continuous-batching scheduler
+(:mod:`repro.serving`): it re-forms a bucket at every launch boundary,
+honours ``--deadline-ms`` with admission control and supports live
+checkpoint hot-swap.  :func:`serve_async` runs a whole batch of requests
+through it.
 
   PYTHONPATH=src python -m repro.launch.serve_gen --nets dcgan,sngan \
       --requests 32 --max-batch 16 --deadline-ms 500
@@ -51,10 +50,10 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.accounting import WORKLOADS, LayerSpec, NetworkSpec
-from repro.launch.batching import pow2_bucket, pow2_floor, take_group
+from repro.launch.batching import pow2_bucket, pow2_floor
 from repro.launch.mesh import make_dev_mesh
 from repro.models.generative import GenerativeModel
-from repro.serving.handoff import split_rows, stack_rows
+from repro.serving.handoff import stack_rows
 
 ALL_NETS = ("dcgan", "sngan", "artgan", "gpgan", "mde", "fst",
             "wavegan", "voxgan", "segnet", "pix2pix")
@@ -384,37 +383,6 @@ class GenServer:
         self.group_join_bytes = self.join_bytes(net, bucket)
         return y
 
-    def serve(self, requests: List[GenRequest]):
-        """LEGACY drain-the-group loop: partitions the whole queue into
-        per-net groups up front and runs them to completion — kept as
-        the closed-loop baseline the async scheduler is benchmarked
-        against (``benchmarks/loadgen.py``) and for batch-mode callers.
-        Live traffic should go through
-        :class:`repro.serving.ContinuousScheduler` (``--sched async``).
-        Returns ({rid: output}, stats)."""
-        queue = list(requests)
-        results: Dict[int, Any] = {}
-        t0 = time.time()
-        groups = 0
-        samples = 0
-        while queue:
-            group, queue = take_group(queue, lambda r: r.net,
-                                      self.max_batch)
-            out = self.run_group(group[0].net, [r.latent for r in group])
-            jax.block_until_ready(out)
-            parts, _ = split_rows(out, len(group))
-            for r, img in zip(group, parts):
-                results[r.rid] = img
-            groups += 1
-            samples += len(group)
-        dt = time.time() - t0
-        return results, {
-            "wall_s": dt, "groups": groups, "requests": samples,
-            "req_per_s": samples / dt if dt else float("inf"),
-            "compiles": self.compile_count,
-            "compile_cache": sorted(k for k in self._compiled),
-        }
-
     def random_requests(self, net: str, n: int, seed: int = 1
                         ) -> List[GenRequest]:
         model, _ = self.model(net)
@@ -429,8 +397,9 @@ def serve_async(server: GenServer, requests: List[GenRequest],
                 deadline_ms: Optional[float] = None):
     """Run ``requests`` through the continuous-batching scheduler
     (:mod:`repro.serving`) — everything arrives at t0, deadlines are
-    relative to arrival.  Returns ({rid: output}, stats) in the same
-    shape as the legacy :meth:`GenServer.serve`."""
+    relative to arrival.  Returns ({rid: output}, stats): the
+    scheduler's stats plus ``wall_s``, ``requests`` (served) and
+    ``req_per_s``."""
     from repro.serving import ContinuousScheduler
     sched = ContinuousScheduler(server)
     t0 = sched.clock.now()
@@ -441,7 +410,7 @@ def serve_async(server: GenServer, requests: List[GenRequest],
     wall = sched.clock.now() - t0
     stats = sched.stats(wall_s=wall)
     stats["wall_s"] = wall
-    stats["requests"] = stats["served"]       # legacy stats key
+    stats["requests"] = stats["served"]
     stats["req_per_s"] = (stats["served"] / wall if wall
                           else float("inf"))
     return results, stats
@@ -469,14 +438,9 @@ def main(argv=None):
                          "scales on N latents per net and chain int8 "
                          "activations between consecutive deconv "
                          "layers (0 = dynamic per-sample scales)")
-    ap.add_argument("--sched", default="async",
-                    choices=["async", "drain"],
-                    help="async = continuous-batching scheduler "
-                         "(repro.serving); drain = legacy group loop")
     ap.add_argument("--deadline-ms", type=float, default=None,
                     help="per-request deadline (relative to arrival); "
-                         "the async scheduler sheds requests it cannot "
-                         "meet")
+                         "the scheduler sheds requests it cannot meet")
     ap.add_argument("--dryrun", action="store_true",
                     help="2 requests on a reduced arch (CI smoke)")
     ap.add_argument("--pretune", action="store_true",
@@ -525,23 +489,17 @@ def main(argv=None):
             r.rid = len(requests)
             requests.append(r)
 
-    if args.sched == "async":
-        results, stats = serve_async(server, requests,
-                                     deadline_ms=args.deadline_ms)
-        print(f"served {stats['requests']} requests in "
-              f"{stats['wall_s']:.2f}s ({stats['req_per_s']:.1f} req/s, "
-              f"{stats['launches']} launches, {stats['compiles']} "
-              f"compiles, {stats['shed']} shed)")
-        lat = stats["latency_ms"]
-        print(f"  latency p50 {lat['p50']}ms p95 {lat['p95']}ms "
-              f"p99 {lat['p99']}ms; goodput "
-              f"{stats['goodput_rps']} req/s; mean occupancy "
-              f"{stats['mean_occupancy']}")
-    else:
-        results, stats = server.serve(requests)
-        print(f"served {stats['requests']} requests in "
-              f"{stats['wall_s']:.2f}s ({stats['req_per_s']:.1f} req/s, "
-              f"{stats['groups']} groups, {stats['compiles']} compiles)")
+    results, stats = serve_async(server, requests,
+                                 deadline_ms=args.deadline_ms)
+    print(f"served {stats['requests']} requests in "
+          f"{stats['wall_s']:.2f}s ({stats['req_per_s']:.1f} req/s, "
+          f"{stats['launches']} launches, {stats['compiles']} "
+          f"compiles, {stats['shed']} shed)")
+    lat = stats["latency_ms"]
+    print(f"  latency p50 {lat['p50']}ms p95 {lat['p95']}ms "
+          f"p99 {lat['p99']}ms; goodput "
+          f"{stats['goodput_rps']} req/s; mean occupancy "
+          f"{stats['mean_occupancy']}")
     for key in stats["compile_cache"]:
         print(f"  compiled cell: {key}")
     for rid in sorted(results)[:2]:

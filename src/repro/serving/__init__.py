@@ -12,10 +12,10 @@ executables (see DESIGN.md "Serving scheduler"):
   and applies :meth:`~ContinuousScheduler.swap_checkpoint` between
   launches with a zero-recompile assertion,
 * :class:`ServingMetrics` — p50/p95/p99 latency, goodput, shed rate,
-  batch-occupancy histograms (the ``BENCH_load.json`` record shape).
+  batch-occupancy histograms.
 
-``repro.launch.serve_gen`` is the CLI over this package;
-``benchmarks/loadgen.py`` is the open-loop load generator.
+``repro.launch.serve_gen`` is the CLI over this package; the on-chip
+benchmark (``bench/``) drives it with closed and open-loop traffic.
 """
 
 from repro.serving.metrics import PERCENTILES, ServingMetrics, percentile

@@ -1,7 +1,7 @@
 """Serving metrics: latency percentiles, goodput, shed rate, occupancy.
 
-One collector instance accompanies one serving run (async scheduler or
-the legacy drain loop) and records three event streams:
+One collector instance accompanies one scheduler run and records three
+event streams:
 
 * **served** — a request completed; carries its latency (completion
   minus *arrival*, so queueing time counts — the user-visible number)
@@ -15,14 +15,12 @@ the legacy drain loop) and records three event streams:
   (``outputs_ms``) and of the server's own phases where it reports
   them (``GenServer.group_ms``: ``inputs_ms``, ``dispatch_ms``).  The
   occupancy histogram (how full each launched bucket was) is the
-  continuous-batching health signal: a drain loop shows trailing
-  1-of-16 buckets, the scheduler should keep buckets near full under
-  load.
+  continuous-batching health signal: the scheduler should keep buckets
+  near full under load.
 
-``summary()`` distils the streams into the ``BENCH_load.json`` record
-shape: p50/p95/p99 latency (overall and per net), goodput (on-time
-completions per second of trace wall time), shed rate, and the
-per-bucket occupancy histogram.
+``summary()`` distils the streams into one record: p50/p95/p99 latency
+(overall and per net), goodput (on-time completions per second of trace
+wall time), shed rate, and the per-bucket occupancy histogram.
 """
 
 from __future__ import annotations
@@ -95,7 +93,7 @@ class ServingMetrics:
         return hist
 
     def summary(self, wall_s: float) -> dict:
-        """The BENCH_load.json record for this run.  ``wall_s`` is the
+        """The summary record for this run.  ``wall_s`` is the
         trace window (last completion minus first arrival)."""
         lats = [r["latency_ms"] for r in self.served]
         on_time = sum(1 for r in self.served if r["on_time"])

@@ -21,8 +21,9 @@ from repro.core.accounting import BENCHMARKS, LayerSpec, NetworkSpec
 from repro.core.deconv import same_deconv_pads
 from repro.core.quant import (QMAX, amax_stat, load_calib, quantize_static,
                               save_calib, scale_from_amax)
-from repro.models.generative import GenerativeModel
-from repro.launch.serve_gen import GenServer, reduced_spec
+from repro.core.ssim import ssim
+from repro.models.generative import GenerativeModel, build
+from repro.launch.serve_gen import GenServer, reduced_spec, serve_async
 
 _PAPER_LAYERS = [(net, layer) for net in BENCHMARKS
                  for layer in BENCHMARKS[net]().deconv_layers()]
@@ -298,6 +299,21 @@ def test_set_calibration_rejects_float_engine():
         m.calibrate({}, n=2)
 
 
+@pytest.mark.parametrize("net", ["dcgan", "sngan"])
+def test_chained_int8_ssim_vs_f32_engine(net):
+    """Whole paper generators at full size, calibrated on 32 latents:
+    at least one plan chains int8 activations through HBM, and the
+    chained engine keeps SSIM >= 0.999 against the f32 engine."""
+    f32m = build(net, "sd_kernel")
+    params = f32m.init(jax.random.PRNGKey(0))
+    i8c = build(net, "sd_kernel", engine_dtype="int8")
+    i8c.calibrate(params, n=32, seed=7)
+    assert any(p.chain_out for p in i8c.engine.plans().values())
+    z = jax.random.normal(jax.random.PRNGKey(1), f32m.input_shape(4))
+    s = float(ssim(f32m.apply(params, z), i8c.apply(params, z)))
+    assert s >= 0.999, f"{net}: chained int8 SSIM {s:.4f} < 0.999"
+
+
 # ---------------------------------------------------------------------------
 # Hot-path purity: NO per-sample amax reduction in the chained jaxpr.
 # ---------------------------------------------------------------------------
@@ -347,7 +363,7 @@ def test_chained_checkpoint_swap_zero_recompile():
     server = GenServer(nets=["g"], specs={"g": spec}, dtype="int8",
                        max_batch=4, calib=8)
     reqs = server.random_requests("g", 4)
-    server.serve(reqs)
+    serve_async(server, reqs)
     assert server.compile_count == 1
     plans = server.model("g")[0].engine.plans()
     assert any(p.chain_out for p in plans.values())  # really chained
@@ -359,7 +375,7 @@ def test_chained_checkpoint_swap_zero_recompile():
     server.swap_checkpoint("g", new_params)
     swapped = server.model("g")[0].engine.plans()
     assert any(p.chain_out for p in swapped.values())
-    results, _ = server.serve(reqs)
+    results, _ = serve_async(server, reqs)
     assert server.compile_count == 1
     # swapped chained outputs track the f32 reference of the NEW params
     ref_model = GenerativeModel(spec, "native")

@@ -18,8 +18,9 @@ from repro.core.deconv import same_deconv_pads
 from repro.core.quant import (QMAX, dequantize, quantize,
                               quantize_act, quantize_channelwise)
 from repro.kernels.autotune import ConvGeom
-from repro.models.generative import GenerativeModel
-from repro.launch.serve_gen import GenServer, reduced_spec
+from repro.core.ssim import ssim
+from repro.models.generative import GenerativeModel, build
+from repro.launch.serve_gen import GenServer, reduced_spec, serve_async
 
 
 # ---------------------------------------------------------------------------
@@ -130,6 +131,22 @@ def test_int8_execute_close_to_f32_engine():
     assert np.abs(y8 - y32).max() / denom < 0.05
 
 
+SSIM_MIN = 0.99     # int8 engine vs f32 engine, whole generator
+
+
+@pytest.mark.parametrize("net", ["dcgan", "sngan"])
+def test_int8_engine_ssim_vs_f32_engine(net):
+    """Whole paper generators at full size: the int8 engine (dynamic
+    per-sample activation scales) keeps SSIM >= SSIM_MIN against the
+    f32 engine on the same weights and latents."""
+    f32m = build(net, "sd_kernel")
+    params = f32m.init(jax.random.PRNGKey(0))
+    i8m = build(net, "sd_kernel", engine_dtype="int8")
+    z = jax.random.normal(jax.random.PRNGKey(1), f32m.input_shape(4))
+    s = float(ssim(f32m.apply(params, z), i8m.apply(params, z)))
+    assert s >= SSIM_MIN, f"{net}: int8 SSIM {s:.4f} < {SSIM_MIN}"
+
+
 # ---------------------------------------------------------------------------
 # BN-scale folding commutes with quantization.
 # ---------------------------------------------------------------------------
@@ -217,7 +234,7 @@ def test_serve_gen_int8_rebind_without_recompile():
                        max_batch=4)
     assert server.engine_dtype == "int8" and server.dtype_name == "int8"
     reqs = server.random_requests("g", 4)
-    results, _ = server.serve(reqs)
+    results, _ = serve_async(server, reqs)
     assert server.compile_count == 1
 
     model, _ = server.model("g")
@@ -226,7 +243,7 @@ def test_serve_gen_int8_rebind_without_recompile():
     model._engine.bind(new_params)
     server._models["g"] = (model, new_params)
 
-    results, _ = server.serve(reqs)
+    results, _ = serve_async(server, reqs)
     assert server.compile_count == 1    # same executable, new int8 plans
     # outputs track the f32 native reference up to quantization noise
     ref_model = GenerativeModel(spec, "native")
@@ -247,6 +264,6 @@ def test_serve_gen_int8_and_f32_cells_coexist():
     k32 = ("g", 4, s32.dtype_name)
     k8 = ("g", 4, "int8")
     assert k32 != k8
-    s32.serve(s32.random_requests("g", 4))
-    s8.serve(s8.random_requests("g", 4))
+    serve_async(s32, s32.random_requests("g", 4))
+    serve_async(s8, s8.random_requests("g", 4))
     assert k32 in s32._compiled and k8 in s8._compiled

@@ -15,10 +15,9 @@ import pytest
 from repro.core.accounting import LayerSpec, NetworkSpec
 from repro.engine import SDEngine, resolve_backend
 from repro.kernels.autotune import ConvGeom, KernelPlan
-from repro.launch.batching import (drain_groups, pow2_bucket, pow2_floor,
-                                   take_group)
-from repro.launch.serve_gen import (GenRequest, GenServer, main,
-                                    reduced_spec, reduced_specs)
+from repro.launch.batching import pow2_bucket, pow2_floor, take_group
+from repro.launch.serve_gen import (GenServer, main, reduced_spec,
+                                    reduced_specs, serve_async)
 from repro.models.generative import GenerativeModel
 
 SPEC = reduced_spec()
@@ -65,7 +64,7 @@ def test_server_clamps_non_pow2_max_batch():
     server = _server(max_batch=12)
     assert server.max_batch == 8
     reqs = server.random_requests("g", 9)               # > clamped cap
-    results, stats = server.serve(reqs)
+    results, stats = serve_async(server, reqs)
     assert set(results) == {r.rid for r in reqs}
     assert all(k[1] & (k[1] - 1) == 0 for k in server._compiled)
 
@@ -105,39 +104,38 @@ def test_take_group_head_of_line_fairness():
         assert ids == sorted(ids)               # per-key FIFO preserved
 
 
-def test_drain_groups_covers_everything():
-    q = list(range(10))
-    groups = drain_groups(q, lambda r: r % 3, max_group=4)
-    assert sorted(x for g in groups for x in g) == q
-    for g in groups:
-        assert len({x % 3 for x in g}) == 1 and len(g) <= 4
-
-
 # ---------------------------------------------------------------------------
 # The serving stack
 # ---------------------------------------------------------------------------
 
-def test_dryrun_smoke():
+@pytest.mark.parametrize("extra", [[], ["--dtype", "int8"],
+                                   ["--dtype", "int8", "--calib", "8"]],
+                         ids=["float32", "int8", "int8-calib"])
+def test_dryrun_smoke(extra, tmp_path, monkeypatch):
     """--dryrun smokes one reduced net per workload family (2-D image,
     1-D audio, 3-D voxel, segmentation decoder): 2 requests each, one
-    compiled cell each."""
-    results, stats = main(["--dryrun"])
+    compiled cell each — in float32, on the int8 engines, and on int8
+    engines calibrated at bind (static scales, chained plans)."""
+    monkeypatch.setenv("REPRO_SD_CALIB_CACHE", str(tmp_path / "calib.json"))
+    results, stats = main(["--dryrun", *extra])
     n_nets = len(reduced_specs())
     assert n_nets == 4
     assert stats["requests"] == 2 * n_nets
     assert stats["compiles"] == n_nets
     assert all(np.isfinite(np.asarray(v)).all() for v in results.values())
+    if "--calib" in extra:                      # scales saved, redirected
+        assert (tmp_path / "calib.json").exists()
 
 
 def test_compile_cache_keyed_on_bucket():
     """Varying request counts that land in the same bucket must NOT
     retrace; a new bucket compiles exactly once more."""
     server = _server(max_batch=8)
-    server.serve(server.random_requests("g", 3))      # bucket 4
+    serve_async(server, server.random_requests("g", 3))          # bucket 4
     assert server.compile_count == 1
-    server.serve(server.random_requests("g", 4, seed=2))   # bucket 4 again
+    serve_async(server, server.random_requests("g", 4, seed=2))  # 4 again
     assert server.compile_count == 1
-    server.serve(server.random_requests("g", 2, seed=3))   # bucket 2: new
+    serve_async(server, server.random_requests("g", 2, seed=3))  # 2: new
     assert server.compile_count == 2
     assert {k[1] for k in server._compiled} == {2, 4}
 
@@ -147,7 +145,7 @@ def test_padding_cropped_and_outputs_match_unbatched():
     output equals the same latent pushed through the model alone."""
     server = _server(max_batch=8)
     reqs = server.random_requests("g", 3)             # padded 3 -> 4
-    results, stats = server.serve(reqs)
+    results, stats = serve_async(server, reqs)
     model, params = server.model("g")
     for r in reqs:
         solo = model.apply(params, jnp.asarray(r.latent)[None])[0]
@@ -159,7 +157,7 @@ def test_server_parity_vs_native_reference():
     """Engine-served outputs == the native-deconv reference model."""
     server = _server(max_batch=4)
     reqs = server.random_requests("g", 4)
-    results, _ = server.serve(reqs)
+    results, _ = serve_async(server, reqs)
     model, params = server.model("g")
     ref_model = GenerativeModel(SPEC, "native")
     x = jnp.stack([jnp.asarray(r.latent) for r in reqs])
@@ -190,9 +188,9 @@ def test_multi_net_fifo_grouping():
     reqs = [ra[0], rb[0], ra[1], rb[1]]
     for i, r in enumerate(reqs):
         r.rid = i
-    results, stats = server.serve(reqs)
+    results, stats = serve_async(server, reqs)
     assert set(results) == {0, 1, 2, 3}
-    assert stats["groups"] == 2                 # one per net
+    assert stats["launches"] == 2               # one per net
 
 
 def test_dp_shard_map_smoke():
@@ -310,7 +308,7 @@ def test_rebind_new_weights_reuses_compiled_executable():
     for the same (net, bucket, dtype) must not retrace."""
     server = _server(max_batch=4)
     reqs = server.random_requests("g", 4)
-    server.serve(reqs)
+    serve_async(server, reqs)
     assert server.compile_count == 1
 
     model, _ = server.model("g")
@@ -319,7 +317,7 @@ def test_rebind_new_weights_reuses_compiled_executable():
     model._engine.bind(new_params)
     server._models["g"] = (model, new_params)
 
-    results, _ = server.serve(reqs)
+    results, _ = serve_async(server, reqs)
     assert server.compile_count == 1        # same executable, new weights
     ref_model = GenerativeModel(SPEC, "native")
     x = jnp.stack([jnp.asarray(r.latent) for r in reqs])
@@ -342,7 +340,7 @@ def test_nd_nets_served_match_native_reference():
     server = GenServer(nets=sorted(specs), specs=specs, max_batch=4)
     for net in sorted(specs):
         reqs = server.random_requests(net, 3)
-        results, _ = server.serve(reqs)
+        results, _ = serve_async(server, reqs)
         model, params = server.model(net)
         ref_model = GenerativeModel(specs[net], "native",
                                     final_tanh=model.final_tanh)
@@ -365,7 +363,7 @@ def test_segnet_head_is_logits():
     reqs = server.random_requests("segnet-dryrun", 2)
     for r in reqs:                      # push logit magnitudes past 1
         r.latent = jnp.asarray(r.latent) * 25.0
-    results, _ = server.serve(reqs)
+    results, _ = serve_async(server, reqs)
     out = np.stack([np.asarray(results[r.rid]) for r in reqs])
     assert out.shape[-1] == 3 and np.isfinite(out).all()
     assert np.abs(out).max() > 1.0      # a tanh head cannot produce this
@@ -394,7 +392,7 @@ def test_serving_tiles_keyed_to_bucket(monkeypatch):
     monkeypatch.setattr(planner_mod, "get_plan", spy)
     server = _server(max_batch=8)
     reqs = server.random_requests("g", 8)
-    server.serve(reqs)
+    serve_async(server, reqs)
     # the group of 8 launches bucket 8: its plan tiles were resolved
     # from batch-8 geometries, not the bind-time plan_batch=1
     assert any(g.b == 8 for g in asked)

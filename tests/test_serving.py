@@ -1,7 +1,8 @@
 """Async serving subsystem (repro.serving): queue/priority semantics,
 starvation-bounded batching, scheduler invariants (nothing lost or
 double-served, deadline shedding, closed compile-shape set), live
-checkpoint hot-swap with zero recompiles, and the open-loop loadgen."""
+checkpoint hot-swap with zero recompiles, and the row hand-off in both
+directions."""
 
 import json
 import os
@@ -11,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.accounting import NetworkSpec
 from repro.launch.batching import pow2_bucket, take_group
 from repro.launch.serve_gen import GenServer, reduced_spec, serve_async
 from repro.models.generative import GenerativeModel
@@ -416,20 +418,28 @@ def test_server_swap_checkpoint_rebinds_engine():
     assert not model.engine.bound_to(params_a)
 
 
-def test_serve_async_matches_legacy_drain_outputs():
-    """Same requests, same params: the async scheduler's outputs equal
-    the legacy drain loop's."""
-    server_a = _server(max_batch=4)
-    server_b = _server(max_batch=4)
-    reqs = server_a.random_requests("g", 6)
-    legacy, _ = server_a.serve(reqs)
-    fresh = server_b.random_requests("g", 6)      # same seed → latents
-    results, stats = serve_async(server_b, fresh, deadline_ms=None)
-    assert stats["shed"] == 0 and stats["served"] == 6
-    for rid in range(6):
-        np.testing.assert_allclose(np.asarray(results[rid]),
-                                   np.asarray(legacy[rid]),
-                                   rtol=1e-5, atol=1e-5)
+def test_serve_async_matches_run_group():
+    """The scheduler hands each request exactly the row the model step
+    computes for it: per net, a full bucket and a cropped one (3 rows
+    padded to 4), ``serve_async`` equals ``run_group`` on the same
+    rows bit for bit."""
+    spec_b = NetworkSpec("g2", list(SPEC.layers))
+    server = GenServer(nets=["g", "g2"], specs={"g": SPEC, "g2": spec_b},
+                       max_batch=4)
+    reqs = []
+    for net, n, seed in (("g", 4, 1), ("g2", 3, 2)):
+        for r in server.random_requests(net, n, seed=seed):
+            r.rid = len(reqs)
+            reqs.append(r)
+    results, stats = serve_async(server, reqs)
+    assert stats["served"] == 7 and stats["launches"] == 2
+    for net in ("g", "g2"):
+        group = [r for r in reqs if r.net == net]
+        ref = np.asarray(server.run_group(net, [r.latent for r in group]))
+        assert ref.shape[0] == len(group)
+        for i, r in enumerate(group):
+            np.testing.assert_array_equal(np.asarray(results[r.rid]),
+                                          ref[i], err_msg=net)
 
 
 # ---------------------------------------------------------------------------
@@ -785,73 +795,6 @@ def test_scheduler_seeds_estimator_from_engine(tmp_path, monkeypatch):
     assert sched.estimator.estimate_ms("g", 4) == pytest.approx(4.0)
 
 
-# ---------------------------------------------------------------------------
-# Loadgen: trace generation + both loops end to end
-# ---------------------------------------------------------------------------
-
-def test_poisson_trace_deterministic_and_ordered():
-    from benchmarks.loadgen import poisson_trace
-    a = poisson_trace(("x", "y"), 10.0, 5, seed=3, deadline_ms=100.0)
-    b = poisson_trace(("x", "y"), 10.0, 5, seed=3, deadline_ms=100.0)
-    assert [(r.net, r.arrival_t) for r in a] == \
-        [(r.net, r.arrival_t) for r in b]
-    assert [r.rid for r in a] == list(range(10))
-    arr = [r.arrival_t for r in a]
-    assert arr == sorted(arr)
-    assert all(r.deadline_t == pytest.approx(r.arrival_t + 0.1)
-               for r in a)
-    assert {r.net for r in a} == {"x", "y"}
-
-
-def test_loadgen_both_loops_account_for_every_request():
-    from benchmarks.loadgen import poisson_trace, run_async, run_drain
-    server = _server(max_batch=4)
-    latents = {"g": np.zeros(16, np.float32)}
-    server.warmup(["g"])
-    trace = poisson_trace(("g",), 40.0, 8, seed=1, deadline_ms=10_000.0,
-                          latents=latents)
-    d = run_drain(server, trace)
-    a = run_async(server, trace)
-    assert d["served"] == 8 and d["shed"] == 0
-    assert a["served"] + a["shed"] == 8
-    for s in (a, d):
-        assert s["latency_ms"]["p50"] is not None
-        assert s["launches"] >= 2
-        assert s["goodput_rps"] is not None
-
-
-def test_loadgen_check_gate(tmp_path):
-    from benchmarks.loadgen import check
-    level = {
-        "util": 0.5, "qps_per_net": 5.0,
-        "async": {"served": 15, "shed": 1, "goodput_ratio": 0.95,
-                  "latency_ms": {"p95": 10.0}},
-        "drain": {"served": 16, "shed": 0, "goodput_ratio": 0.95,
-                  "latency_ms": {"p95": 20.0}},
-        "p95_async_ms": 10.0, "p95_drain_ms": 20.0,
-        "async_p95_better": True, "common_goodput": True,
-    }
-    data = {"nets": ["a", "b"], "n_per_net": 8,
-            "levels": [dict(level) for _ in range(3)],
-            "headline": {"highest_common_goodput_level": 2,
-                         "async_beats_drain_p95": True,
-                         "async_p95_ms": 10.0, "drain_p95_ms": 20.0}}
-    path = tmp_path / "BENCH_load.json"
-    path.write_text(json.dumps(data))
-    check(str(path))                               # passes
-
-    data["headline"]["async_beats_drain_p95"] = False
-    path.write_text(json.dumps(data))
-    with pytest.raises(AssertionError, match="p95"):
-        check(str(path))
-
-    data["headline"]["async_beats_drain_p95"] = True
-    data["levels"][0]["async"]["served"] = 10      # lost requests
-    path.write_text(json.dumps(data))
-    with pytest.raises(AssertionError, match="lost"):
-        check(str(path))
-
-
 def test_server_warmup_compiles_full_ladder():
     server = _server(max_batch=8)
     n = server.warmup(["g"])
@@ -868,14 +811,9 @@ def test_server_warmup_compiles_full_ladder():
 def test_dryrun_uses_async_scheduler_with_deadlines():
     from repro.launch.serve_gen import main
     results, stats = main(["--dryrun"])
-    # async-only stats shape: latency percentiles + shed accounting
+    # scheduler stats: latency percentiles + shed accounting
     assert stats["shed"] == 0
     assert stats["latency_ms"]["p95"] is not None
     assert stats["served_on_time"] == stats["served"] == 8
     assert stats["requests"] == 8
 
-
-def test_cli_drain_mode_still_available():
-    from repro.launch.serve_gen import main
-    results, stats = main(["--dryrun", "--sched", "drain"])
-    assert stats["requests"] == 8 and "groups" in stats
