@@ -23,7 +23,8 @@ This module provides:
   exists (replaces the old hard-coded ``_pick_th``).
 * :func:`vmem_plan_bytes` — the VMEM footprint model the heuristic and
   the candidate filter share: input band (halo included), filter block,
-  f32 accumulator and output tile — not just the filter block.
+  f32 accumulator and output tile — not just the filter block — with
+  the lanes a phase-packed launch saves.
 * :func:`candidate_plans` — the search space for a geometry.
 * :func:`tune` — measure every candidate with a caller-supplied runner
   and persist the winner to a JSON cache.
@@ -60,6 +61,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 
 from repro.core.iohelpers import atomic_write_json
+from repro.kernels.sd_conv import phase_packed
 
 _ENV_CACHE = "REPRO_SD_PLAN_CACHE"
 _DEFAULT_CACHE = os.path.join(os.path.expanduser("~"), ".cache", "repro",
@@ -313,14 +315,18 @@ def vmem_plan_bytes(geom: ConvGeom, plan: KernelPlan) -> int:
         return 2 * (isz * (band + filt) + 4 * out) + 4 * (acc + vtmp)
     # Mirrors sd_conv.sd_fused_pallas: conv columns (residual included)
     # round up to the operand's sublane tile, the band to 8 rows, and
-    # every VMEM buffer pads its channels to 128 lanes.
+    # every VMEM buffer pads its channels to 128 lanes — a phase-packed
+    # launch's filter block and accumulator once over all the phases'
+    # lanes, an unpacked one's per phase.
     tile = 32 // isz
     cols = _round_up(tw + 1, tile)
     lanes_in, lanes_out = _round_up(plan.tcin, 128), _round_up(plan.tcout,
                                                                128)
+    lanes_ph = (_round_up(phases * plan.tcout, 128)
+                if phase_packed(phases, plan.tcout) else phases * lanes_out)
     band = (th + kt) * _round_up(cols + ktw - 1, tile) * lanes_in * isz
-    filt = kt * ktw * phases * _round_up(plan.tcin, tile) * lanes_out * isz
-    acc = phases * (th + 1) * cols * lanes_out * 4
+    filt = kt * ktw * _round_up(plan.tcin, tile) * lanes_ph * isz
+    acc = (th + 1) * cols * lanes_ph * 4
     ytile = th * s * _round_up(tw * sw, 8) * lanes_out * 4
     # The epilogue interleaves into the f32 tile, then writes the output
     # block: 1 byte per element for chained int8 launches, else 4.

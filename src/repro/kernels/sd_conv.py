@@ -8,12 +8,14 @@ Three kernels:
   FULL convs and cropped outputs never materialise uncropped copies in
   HBM.
 * ``sd_fused_kernel``       — the same convolution, but each block *also*
-  performs the paper's stride-``s`` output write: every phase
-  accumulates on its own, the bias + activation epilogue runs per phase,
-  and strided VMEM stores interleave the phases into the deconv output
-  tile.  The ``P_K`` + user-padding crop is folded into the write
-  (phase-offset stores + trimmed ``out_shape``) — the tile leaves VMEM
-  in final output geometry.
+  performs the paper's stride-``s`` output write: the bias + activation
+  epilogue runs on the accumulated phases, and strided VMEM stores
+  interleave them into the deconv output tile.  Narrow Cout tiles
+  (under one 128-lane tile, :func:`phase_packed`) pack the ``s^2``
+  phases side by side in the matmul's lanes; wider ones accumulate
+  each phase on its own.  The ``P_K`` + user-padding crop is folded
+  into the write (phase-offset stores + trimmed ``out_shape``) — the
+  tile leaves VMEM in final output geometry.
 * ``sd_filter_grad_kernel`` — the filter-gradient VALID conv of the SD
   backward (``dw[t] = sum_{b,v} xpad[b, v+t] dy1[b, v]``): one MXU GEMM
   per tap over a band of ``dy1`` rows, with batch and row bands as the
@@ -34,8 +36,11 @@ TPU mapping (see DESIGN.md "Memory traffic"):
     ``arbitrary``; the four outer axes are ``parallel``.  Row/col grids
     ceil-divide the output — trailing partial blocks are Pallas-managed.
   - each step loads an input band with a (K_T - 1) halo per spatial dim
-    and issues K_Th*K_Tw MXU matmuls (times ``s^2`` phases in the fused
-    kernel) of shape (rows*cols, TCin) x (TCin, TCout).
+    and issues K_Th*K_Tw MXU matmuls of shape (rows*cols, TCin) x
+    (TCin, N): N = TCout in the conv kernel; in the fused kernel N =
+    s^2*TCout once per tap when the phases are packed, else N = TCout
+    once per tap and phase.  A matmul and the accumulator pad N to
+    whole 128-lane tiles, which is what packing narrow tiles saves.
   - partial sums live in a VMEM scratch accumulator that persists across
     the Cin-tile grid steps; the output block is written exactly once, by
     the epilogue at the last Cin tile.
@@ -166,14 +171,22 @@ def _dot(a: jax.Array, b: jax.Array, contract: int = 1) -> jax.Array:
                                preferred_element_type=jnp.float32)
 
 
+def phase_packed(phases: int, tcout: int) -> bool:
+    """Whether a fused launch packs its ``phases`` into the matmul's
+    lanes: a Cout tile narrower than one 128-lane tile would otherwise
+    pad every per-phase matmul and accumulator slab to 128 lanes."""
+    return phases > 1 and tcout < 128
+
+
 def _accumulate(x_ref, w_ref, acc_ref, *, kth: int, ktw: int, rows: int,
                 cols: int) -> None:
     """``acc_ref[p, r] += sum_taps x[r + kh, kw : kw + cols] @ w[kh, kw, p]``
-    for conv rows ``r < rows`` and every phase ``p``.
+    for conv rows ``r < rows`` and every slab ``p``.
 
-    x_ref: (1, band_h, band_w, TCin) band; w_ref: (KTh, KTw, P, TCin,
-    TCout); acc_ref: (P, rows, cols, TCout).  Each tap's im2col slab is
-    loaded once per row chunk and feeds one MXU matmul per phase."""
+    x_ref: (1, band_h, band_w, TCin) band; w_ref: (KTh, KTw, P, TCin, N);
+    acc_ref: (P, rows, cols, N).  Each tap's im2col slab is loaded once
+    per row chunk and feeds one MXU matmul per slab: P phases of N =
+    TCout, or one slab of N = s^2*TCout when the phases are packed."""
     tcin = x_ref.shape[-1]
 
     def step(r, n):
@@ -286,16 +299,23 @@ def sd_conv_pallas(x: jax.Array, w: jax.Array, *, th: int = 8,
 def _sd_fused_body(x_ref, w_ref, b_ref, *rest, kth: int, ktw: int,
                    rh: int, cols: int, th: int, tw: int, sh: int, sw: int,
                    res_h: int, res_w: int, out_h: int, out_w: int,
-                   act: str, quant: bool):
-    """Conv + per-phase epilogue + strided-store interleave.
+                   act: str, quant: bool, packed: bool):
+    """Conv + epilogue + strided-store interleave.
 
-    w_ref holds phase-major split filters: ``w_ref[kh, kw, p]`` is the
-    (TCin, TCout) matrix of phase ``p = py*sw + px``, so each phase
-    accumulates in its own (rh, cols, TCout) slab of ``acc_ref``.  The
-    step computes ``rh`` conv rows (``th + 1`` when the residual crop
-    ``res`` is nonzero) of ``cols >= tw (+ 1)`` conv columns.  The
-    epilogue at the last cin tile adds the per-oc bias and applies the
-    activation per phase, then stores phase (py, px) at rows
+    Unpacked (``tcout`` of 128 lanes or more): w_ref holds phase-major
+    split filters, ``w_ref[kh, kw, p]`` the (TCin, TCout) matrix of
+    phase ``p = py*sw + px``, so each phase accumulates in its own (rh,
+    cols, TCout) slab of ``acc_ref``, and the epilogue adds the per-oc
+    bias and applies the activation per phase.  Packed
+    (:func:`phase_packed`): ``w_ref[kh, kw, 0]`` is one (TCin,
+    ss*TCout) matrix, lane ``p*TCout + oc``, so one matmul per tap
+    feeds one (rh, cols, ss*TCout) slab; the epilogue applies the
+    packed (tiled) bias and the activation once over that slab, in
+    place, and reads phase p back as lanes ``[p*TCout, (p+1)*TCout)``.
+
+    The step computes ``rh`` conv rows (``th + 1`` when the residual
+    crop ``res`` is nonzero) of ``cols >= tw (+ 1)`` conv columns.  The
+    epilogue at the last cin tile stores phase (py, px) at rows
     ``py - res_h (mod sh)`` + k*sh and cols ``px - res_w (mod sw)`` +
     k*sw of the f32 tile ``y_ref`` — the interleave and the residual crop
     in one strided store — and writes the tile once, cast to the output
@@ -304,7 +324,8 @@ def _sd_fused_body(x_ref, w_ref, b_ref, *rest, kth: int, ktw: int,
     ``quant``: int8 launch — the accumulator is exact int32 and a fourth
     operand carries the combined per-(sample, phase, oc) dequant scale
     (activation scale x folded per-channel filter scale), multiplied in
-    before the bias.
+    before the bias.  A packed int8 launch keeps the activated f32
+    values in the int32 accumulator as their bit patterns.
     """
     if quant:
         s_ref, o_ref, acc_ref, y_ref = rest
@@ -321,6 +342,18 @@ def _sd_fused_body(x_ref, w_ref, b_ref, *rest, kth: int, ktw: int,
 
     @pl.when(ci == pl.num_programs(4) - 1)
     def _epilogue():
+        tc = y_ref.shape[-1]
+        if packed:
+            def activate(r, n):
+                y = acc_ref[0, pl.ds(r, n)].astype(jnp.float32)
+                if quant:
+                    y = y * s_ref[0]
+                y = _apply_act(y + b_ref[...], act)
+                if quant:
+                    y = jax.lax.bitcast_convert_type(y, jnp.int32)
+                acc_ref[0, pl.ds(r, n)] = y
+
+            _for_row_chunks(rh, cols, activate)
         for py in range(sh):
             for px in range(sw):
                 p = py * sw + px
@@ -330,10 +363,16 @@ def _sd_fused_body(x_ref, w_ref, b_ref, *rest, kth: int, ktw: int,
                 r0, c0 = int(dy < 0), int(dx < 0)
 
                 def phase(k, n, p=p, dy=dy, dx=dx, r0=r0, c0=c0):
-                    y = acc_ref[p, pl.ds(k + r0, n)].astype(jnp.float32)
-                    if quant:
-                        y = y * s_ref[0, p:p + 1, :]
-                    y = _apply_act(y + b_ref[...], act)
+                    if packed:
+                        y = acc_ref[0, pl.ds(k + r0, n)][
+                            ..., p * tc:(p + 1) * tc]
+                        if quant:
+                            y = jax.lax.bitcast_convert_type(y, jnp.float32)
+                    else:
+                        y = acc_ref[p, pl.ds(k + r0, n)].astype(jnp.float32)
+                        if quant:
+                            y = y * s_ref[0, p:p + 1, :]
+                        y = _apply_act(y + b_ref[...], act)
                     y_ref[pl.ds(k * sh + dy % sh, n, stride=sh),
                           pl.ds(dx % sw, tw, stride=sw), :] = (
                               y[:, c0:c0 + tw])
@@ -369,9 +408,13 @@ def sd_fused_pallas(x: jax.Array, ws_ocmajor: jax.Array, s, *,
     x:  (B, H, W, Cin) — the *unpadded* input; ``pad`` (the ``P_I``
         halo) is applied by the wrapper's one zero pad.
     ws_ocmajor: (KTh, KTw, Cin, Cout*sh*sw), channel c = oc*sh*sw + phase
-        (the plan's stored layout); the wrapper relays it phase-major
-        ``(KTh, KTw, sh*sw, Cin, Cout)`` for the kernel — a per-call
-        copy of the filters (see DESIGN.md "Memory traffic").
+        (the plan's stored layout); the wrapper relays it for the kernel
+        — a per-call copy of the filters (see DESIGN.md "Memory
+        traffic") — phase-major ``(KTh, KTw, sh*sw, Cin, Cout)``, or,
+        for a phase-packed launch (:func:`phase_packed`), with the
+        phases in the lanes of each Cout tile: ``(KTh, KTw, Cin,
+        Cout/TCout, sh*sw, TCout)``, the core's n-major order when the
+        tile is the whole Cout.
     s:  interleave factor — an int (square, the 2-D path) or an
         ``(sh, sw)`` pair (the 1-D lowering passes ``(1, s)``).
     bias: (Cout,) added per output channel in the epilogue (folded-BN
@@ -437,35 +480,54 @@ def sd_fused_pallas(x: jax.Array, ws_ocmajor: jax.Array, s, *,
     # The q whole-interleave-row crop shifts the band origin by q rows.
     xw = _window(x, (plo_h - q_h, plo_w - q_w),
                  ((nh - 1) * th + band_h, (nw - 1) * tw + band_w))
-    wpm = ws_ocmajor.reshape(kth, ktw, cin, cout, ss).transpose(
-        0, 1, 4, 2, 3)
     # A single-grid-step dim's output block is the whole (possibly
     # odd-sized) dim; otherwise th*s | block offsets.
     out_h, out_w = min(th * sh, oh), min(tw * sw, ow)
+    packed = phase_packed(ss, tcout)
+    # Accumulator slabs and the lanes of each: ss phases of TCout, or
+    # one slab of the ss phases packed side by side.
+    slabs, lanes = (1, ss * tcout) if packed else (ss, tcout)
+
+    def phase_lanes(a: jax.Array) -> jax.Array:
+        """(..., Cout*ss) oc-major channels -> (..., slabs, lanes) in
+        the launch's lane order: phase-major (..., ss, Cout), or packed
+        (..., 1, Cout*ss) with lane ``co*ss*TCout + p*TCout + oc`` in
+        Cout tile ``co``."""
+        if not packed:
+            return jnp.swapaxes(a.reshape(*a.shape[:-1], cout, ss), -1, -2)
+        a = a.reshape(*a.shape[:-1], cout // tcout, tcout, ss)
+        return jnp.swapaxes(a, -1, -2).reshape(*a.shape[:-3], 1, cout * ss)
+
+    wk = jnp.moveaxis(phase_lanes(ws_ocmajor), -2, 2)
+    if packed:
+        # The epilogue adds the bias to the packed slab: one copy per
+        # phase, in the filters' lane order.
+        bias2d = jnp.tile(bias2d.reshape(-1, 1, tcout), (1, ss, 1))
+        bias2d = bias2d.reshape(1, cout * ss)
 
     body = functools.partial(
         _sd_fused_body, kth=kth, ktw=ktw, rh=rh, cols=cols, th=th, tw=tw,
         sh=sh, sw=sw, res_h=res_h, res_w=res_w, out_h=out_h, out_w=out_w,
-        act=act, quant=quant)
+        act=act, quant=quant, packed=packed)
     in_specs = [
         _band_spec(band_h, band_w, tcin, th, tw, nw, cin // tcin),
-        pl.BlockSpec((kth, ktw, ss, tcin, tcout),
+        pl.BlockSpec((kth, ktw, slabs, tcin, lanes),
                      lambda bi, i, j, co, ci: (0, 0, 0, ci, co)),
-        pl.BlockSpec((1, tcout), lambda bi, i, j, co, ci: (0, co)),
+        pl.BlockSpec((1, lanes), lambda bi, i, j, co, ci: (0, co)),
     ]
-    operands = [xw, wpm, bias2d]
+    operands = [xw, wk, bias2d]
     if quant:
-        # Dequant scales, phase-major (B|1, ss, Cout): one (1, ss,
-        # TCout) block staged per (batch, cout-tile) grid step.  A
-        # single-row scale is the *static* calibrated case — bind it
-        # with a batch-independent index map so all samples share it.
-        srows = scale.astype(jnp.float32).reshape(-1, cout, ss)
-        srows = srows.transpose(0, 2, 1)
+        # Dequant scales in the filters' lane order, (B|1, ss, Cout) or
+        # packed (B|1, 1, Cout*ss): one (1, slabs, lanes) block staged
+        # per (batch, cout-tile) grid step.  A single-row scale is the
+        # *static* calibrated case — bind it with a batch-independent
+        # index map so all samples share it.
+        srows = phase_lanes(scale.astype(jnp.float32))
         if srows.shape[0] == 1:
             smap = lambda bi, i, j, co, ci: (0, 0, co)
         else:
             smap = lambda bi, i, j, co, ci: (bi, 0, co)
-        in_specs.append(pl.BlockSpec((1, ss, tcout), smap))
+        in_specs.append(pl.BlockSpec((1, slabs, lanes), smap))
         operands.append(srows)
     return pl.pallas_call(
         body,
@@ -475,7 +537,7 @@ def sd_fused_pallas(x: jax.Array, ws_ocmajor: jax.Array, s, *,
                                lambda bi, i, j, co, ci: (bi, i, j, co)),
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, cout), out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((ss, rh, cols, tcout),
+            pltpu.VMEM((slabs, rh, cols, lanes),
                        jnp.int32 if quant else jnp.float32),
             pltpu.VMEM((th * sh, tw * sw, tcout), jnp.float32)],
         compiler_params=_compiler_params(4, 1),

@@ -50,6 +50,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.accounting import WORKLOADS, LayerSpec, NetworkSpec
+from repro.kernels.sd_conv import phase_packed
 from repro.launch.batching import pow2_bucket, pow2_floor
 from repro.launch.mesh import make_dev_mesh
 from repro.models.generative import GenerativeModel
@@ -152,6 +153,8 @@ class GenServer:
         self.compile_count = 0          # incremented at trace time
         self.group_ms: Dict[str, float] = {}   # last run_group's phases
         self.group_join_bytes = 0       # and the bytes its joins wrote
+        self.group_packed_share = 0.0   # and its share of packed MACs
+        self._packed_share: Dict[Tuple[str, int], float] = {}
         self._mesh = None
         if self.dp > 1 or self.mp > 1:
             need = self.dp * self.mp
@@ -300,6 +303,28 @@ class GenServer:
         the traffic a kernel reading two operands would not make."""
         return bucket * self._specs[net].join_elems() * self.dtype.itemsize
 
+    def packed_share(self, net: str, bucket: int) -> float:
+        """Share of one launch's fused-kernel MACs that run phase-packed
+        (:func:`repro.kernels.sd_conv.phase_packed`): per deconv layer
+        the fused kernel launches (rank 1 or 2 on the ``fused``
+        backend), its ``sd_macs`` from the layer shape, packed when the
+        bucket's plan has a Cout tile narrower than 128 lanes.  0 when
+        no fused kernel runs.  Computed once per (net, bucket)."""
+        key = (net, bucket)
+        if key not in self._packed_share:
+            _, plans = self._serving_args(net, bucket)
+            layers = {l.name: l for l in self._specs[net].deconv_layers()}
+            fused = packed = 0
+            for name, plan in plans.items():
+                if plan.backend != "fused" or plan.rank > 2:
+                    continue
+                macs = layers[name].sd_macs()
+                tcout = plan.tile.tcout if plan.tile else plan.cout_local
+                fused += macs
+                packed += macs if phase_packed(plan.phases, tcout) else 0
+            self._packed_share[key] = packed / fused if fused else 0.0
+        return self._packed_share[key]
+
     def compiled(self, net: str, bucket: int):
         """The jitted padded-batch executable for one cell (see
         :meth:`cell_key`).
@@ -368,7 +393,8 @@ class GenServer:
         copied to the device in one call) and ``serve.dispatch``
         (``dispatch_ms``: the compiled call and the crop, enqueued, not
         waited on); ``group_join_bytes`` is the launch's
-        :meth:`join_bytes`."""
+        :meth:`join_bytes` and ``group_packed_share`` its
+        :meth:`packed_share`."""
         n = len(latents)
         bucket = self.bucket(n)
         lean_params, plans = self._serving_args(net, bucket)
@@ -381,6 +407,7 @@ class GenServer:
         self.group_ms = {"inputs_ms": (t1 - t0) * 1e3,
                          "dispatch_ms": (time.perf_counter() - t1) * 1e3}
         self.group_join_bytes = self.join_bytes(net, bucket)
+        self.group_packed_share = self.packed_share(net, bucket)
         return y
 
     def random_requests(self, net: str, n: int, seed: int = 1

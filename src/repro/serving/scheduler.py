@@ -39,7 +39,9 @@ This module replaces that with an event loop that re-forms a batch at
   output into its rows, :mod:`repro.serving.handoff`).  The launch
   record carries the host ms of ``sched.outputs`` and of the server's
   phases, ``join_bytes`` (what the server's skip joins wrote, from
-  ``GenServer.group_join_bytes``) and ``handoff``: the path the
+  ``GenServer.group_join_bytes``), ``packed_share`` (the share of its
+  fused-kernel MACs that ran phase-packed, from
+  ``GenServer.group_packed_share``) and ``handoff``: the path the
   hand-off took (``"split"``, ``"index"``, or None when no outputs are
   collected).
 
@@ -322,13 +324,15 @@ class ContinuousScheduler:
                              launch=len(self.metrics.launches), net=net,
                              bucket=bucket, n=len(reqs)):
             t0 = self.clock.now()
-            phase_ms, join_bytes = {}, 0
+            phase_ms, join_bytes, packed_share = {}, 0, 0.0
             if self._launch_fn is not None:
                 out = self._launch_fn(net, [r.latent for r in reqs], bucket)
             else:
                 out = self.server.run_group(net, [r.latent for r in reqs])
                 phase_ms = getattr(self.server, "group_ms", {})
                 join_bytes = getattr(self.server, "group_join_bytes", 0)
+                packed_share = getattr(self.server, "group_packed_share",
+                                       0.0)
                 with TraceAnnotation("sched.block"):
                     jax.block_until_ready(out)
             done = self.clock.now()
@@ -348,6 +352,7 @@ class ContinuousScheduler:
             rec = self.metrics.record_launch(net, bucket, len(reqs),
                                              (done - t0) * 1e3, **phase_ms)
             rec["join_bytes"] = join_bytes
+            rec["packed_share"] = packed_share
             parts, rec["handoff"] = None, None
             if self.collect_outputs and out is not None:
                 parts, rec["handoff"] = split_rows(out, len(reqs))
@@ -373,6 +378,10 @@ class ContinuousScheduler:
                           for path in (SPLIT, INDEX)}
         rec["join_bytes"] = sum(r["join_bytes"]
                                 for r in self.metrics.launches)
+        # Mean over launches: each launch's share of its fused MACs.
+        launches = self.metrics.launches
+        rec["packed_share"] = (sum(r["packed_share"] for r in launches)
+                               / len(launches) if launches else 0.0)
         rec["compiles"] = getattr(self.server, "compile_count", None)
         cells = getattr(self.server, "_compiled", None)
         if cells is not None:

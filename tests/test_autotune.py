@@ -242,3 +242,53 @@ def test_candidates_include_width_tiles_on_wide_geoms():
     for p in candidate_plans(geom, max_candidates=8,
                              enforce_budget=True):
         assert vmem_plan_bytes(geom, p) <= VMEM_BUDGET
+
+
+# (bucket, input hw, cin, cout, k) at the cells' buckets, all stride 2.
+_CELL_LAYERS = {
+    "dcgan_d3": (256, (32, 32), 64, 3, 5),
+    "mde_up1": (16, (128, 256), 32, 16, 3),
+    "pix2pix_u8": (32, (128, 128), 128, 3, 4),
+    "dcgan_d1": (256, (8, 8), 256, 128, 5),
+    "mde_up4": (16, (16, 32), 256, 128, 3),
+    "pix2pix_u5": (32, (16, 16), 1024, 256, 4),
+}
+
+
+def _cell_geom(name):
+    from repro.core.deconv import same_deconv_pads
+    b, hw, cin, cout, k = _CELL_LAYERS[name]
+    return ConvGeom.from_deconv(b, *hw, cin, cout, k, 2,
+                                padding=same_deconv_pads(k, 2))
+
+
+@pytest.mark.parametrize("name", ["dcgan_d3", "mde_up1", "pix2pix_u8"])
+def test_vmem_model_counts_packed_phase_lanes(name, monkeypatch):
+    """A phase-packed launch's filter block and accumulator take
+    round_up(s^2 * tcout, 128) lanes, not s^2 tiles of 128: the model
+    differs from the per-phase count by exactly those lanes, and the
+    heuristic plan stays within the budget."""
+    import repro.kernels.autotune as at
+    geom = _cell_geom(name)
+    plan = heuristic_plan(geom)
+    assert plan.tcout == geom.cout < 128
+    packed = vmem_plan_bytes(geom, plan)
+    assert packed <= VMEM_BUDGET
+    monkeypatch.setattr(at, "phase_packed", lambda phases, tcout: False)
+    per_phase = vmem_plan_bytes(geom, plan)
+    cols = -(-((plan.tw or geom.ow) + 1) // 8) * 8
+    lane_bytes = (2 * geom.kt ** 2 * -(-plan.tcin // 8) * 8 * 4
+                  + (plan.th + 1) * cols * 4)
+    saved = 4 * 128 - -(-4 * geom.cout // 128) * 128
+    assert per_phase - packed == saved * lane_bytes
+
+
+@pytest.mark.parametrize("name,nbytes", [
+    ("dcgan_d1", 5_873_664), ("mde_up4", 7_913_472),
+    ("pix2pix_u5", 8_175_616)])
+def test_vmem_model_unchanged_for_wide_tiles(name, nbytes):
+    """tcout >= 128: one phase per lane tile, as before packing."""
+    geom = _cell_geom(name)
+    plan = heuristic_plan(geom)
+    assert plan.tcout >= 128
+    assert vmem_plan_bytes(geom, plan) == nbytes

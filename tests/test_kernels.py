@@ -15,7 +15,9 @@ from repro.core.deconv import depth_to_space
 from repro.kernels.ops import (sd_conv2d_valid, sd_deconv_fused,
                                sd_deconv_kernel, ws_to_ocmajor)
 from repro.kernels.ref import conv2d_valid_ref, sd_deconv_fused_ref
-from repro.kernels.sd_conv import sd_conv_pallas
+from repro.kernels.sd_conv import (phase_packed, sd_conv_pallas,
+                                   sd_fused_pallas)
+from repro.sd.plan import to_ocmajor
 
 
 def _rand(shape, seed=0, dtype=jnp.float32):
@@ -184,3 +186,83 @@ def test_property_fused_kernel(K, s, H, Cin, Cout, seed):
     ref = native_deconv(x, w, s, p)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# Phase-packed lanes (Cout tiles under 128 lanes) vs the ref.py oracle
+# ---------------------------------------------------------------------------
+
+# name: (x spatial, s, pad, crop, out_space, th, tw).  "s2-res-op" has a
+# residual crop in both dims and two output_padding rows past the
+# support; "1d-s3" is the rank-1 lowering's (1, s) interleave.
+PACKED_GEOMS = {
+    "s2-res-op": ((5, 6), (2, 2), ((1, 1), (1, 1)), (1, 1), (13, 12), 2, 3),
+    "s3": ((5, 6), (3, 3), ((1, 1), (1, 1)), (2, 0), (17, 21), 2, 0),
+    "1d-s3": ((1, 6), (1, 3), ((0, 0), (2, 2)), (0, 4), (1, 22), 1, 4),
+}
+PACKED_CASES = ([(g, m) for g in PACKED_GEOMS
+                 for m in ("float32", "bfloat16")]
+                + [("s2-res-op", m) for m in ("int8", "int8-static",
+                                              "int8-chained")]
+                + [("1d-s3", "int8-static")])
+
+
+def _packed_ref(x, ws, s, pad, crop, out_space, bias, act, scale):
+    """``sd_deconv_fused_ref`` on the padded input, dequantized per
+    n-major channel, cropped (and zero-extended) to the output, then
+    bias and activation."""
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), *pad, (0, 0)))
+    y = conv2d_valid_ref(xp, ws.astype(jnp.float32))
+    if scale is not None:
+        cout = ws.shape[-1] // (s[0] * s[1])
+        y = y * jnp.swapaxes(scale.reshape(-1, cout, s[0] * s[1]), 1, 2
+                             ).reshape(-1, 1, 1, ws.shape[-1])
+    full = depth_to_space(y, s)
+    (ch, cw), (oh, ow) = crop, out_space
+    full = jnp.pad(full, ((0, 0), (0, max(0, ch + oh - full.shape[1])),
+                          (0, max(0, cw + ow - full.shape[2])), (0, 0)))
+    out = full[:, ch:ch + oh, cw:cw + ow] + bias
+    return jnp.tanh(out) if act == "tanh" else jnp.maximum(out, 0.0)
+
+
+@pytest.mark.parametrize("cout", [3, 16, 64, 128])
+@pytest.mark.parametrize("geom,mode", PACKED_CASES)
+def test_fused_packed_phases_match_ref(geom, mode, cout):
+    """The fused kernel against ``ref.sd_deconv_fused_ref`` for Cout
+    3, 16 and 64 (phases packed in the lanes) and 128 (one phase per
+    lane tile, the unpacked control): f32, bf16, and int8 with dynamic
+    or static dequant scales and with the chained int8 output."""
+    hw, s, pad, crop, out_space, th, tw = PACKED_GEOMS[geom]
+    ss, cin = s[0] * s[1], 8
+    kt = (1 if s[0] == 1 else 2, 3 if s[0] == 1 else 2)
+    assert phase_packed(ss, cout) == (cout < 128)
+    rng = np.random.RandomState(cout + ss)
+    x = rng.randn(2, *hw, cin)
+    ws = rng.randn(*kt, cin, ss * cout) * 0.3
+    bias = jnp.asarray(rng.randn(cout), jnp.float32)
+    act = "relu" if mode.startswith("int8") else "tanh"
+    scale = out_dtype = None
+    if mode.startswith("int8"):
+        x = np.clip(np.round(x * 40), -127, 127).astype(np.int8)
+        ws = np.clip(np.round(ws * 200), -127, 127).astype(np.int8)
+        rows = 2 if mode == "int8" else 1
+        scale = jnp.asarray(rng.uniform(2e-3, 2e-2, (rows, ss * cout)),
+                            jnp.float32)
+        out_dtype = "int8" if mode == "int8-chained" else None
+    dtype = jnp.int8 if mode.startswith("int8") else jnp.dtype(mode)
+    x, ws = jnp.asarray(x, dtype), jnp.asarray(ws, dtype)
+    out = sd_fused_pallas(x, to_ocmajor(ws, s), s, bias=bias, act=act,
+                          scale=scale, th=th, tw=tw, pad=pad, crop=crop,
+                          out_space=out_space, out_dtype=out_dtype,
+                          interpret=True)
+    ref = _packed_ref(x, ws, s, pad, crop, out_space, bias, act, scale)
+    assert out.shape == (2, *out_space, cout)
+    if out_dtype == "int8":
+        assert out.dtype == jnp.int8
+        ref = jnp.clip(jnp.round(ref), -127, 127)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref), atol=1)
+        return
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref), rtol=tol, atol=tol)

@@ -430,3 +430,40 @@ def test_server_pretune_fused_persists(tmp_path, monkeypatch):
         algo = "wino" if plans[name].backend == "winograd" else ""
         geom = model.engine.layer_geom(layer, 2, algo=algo)
         assert plans[name].tile == tuned[geom.key()]
+
+
+# ---------------------------------------------------------------------------
+# Phase-packed share of the fused kernel's MACs
+# ---------------------------------------------------------------------------
+
+WIDE = NetworkSpec("wide", [
+    LayerSpec("fc", 16, 4 * 4 * 128, name="project"),
+    LayerSpec("deconv", 128, 256, k=4, s=2, in_hw=(4, 4), name="d1"),
+    LayerSpec("deconv", 256, 128, k=4, s=2, in_hw=(8, 8), name="d2"),
+])
+
+
+@pytest.mark.parametrize("net,bucket", [
+    ("dcgan", 256), ("mde", 16), ("pix2pix", 32), ("wide", 4)])
+def test_packed_share_is_the_narrow_deconvs_mac_share(net, bucket):
+    """At each cell's bucket, the share of the fused kernel's MACs that
+    run phase-packed is the share of the Cout < 128 deconvs' SD MACs,
+    from the layer shapes; 0 where every deconv has Cout >= 128."""
+    specs = {"wide": WIDE} if net == "wide" else None
+    server = GenServer(nets=[net], specs=specs, backend="fused",
+                       max_batch=bucket)
+    layers = server.model(net)[0].spec.deconv_layers()
+    narrow = sum(l.sd_macs() for l in layers if l.cout < 128)
+    want = narrow / sum(l.sd_macs() for l in layers)
+    assert (want > 0) == (net != "wide")
+    assert server.packed_share(net, bucket) == pytest.approx(want)
+
+
+def test_packed_share_on_launch_records_and_stats():
+    """Each launch record carries its cell's packed share and stats()
+    reports it; the xla backend runs no fused kernel, so 0."""
+    for backend, want in (("fused", 1.0), ("xla", 0.0)):
+        server = _server(max_batch=4, backend=backend)
+        _, stats = serve_async(server, server.random_requests("g", 3))
+        assert server.packed_share("g", 4) == want     # both deconvs narrow
+        assert stats["packed_share"] == want

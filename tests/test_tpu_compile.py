@@ -36,6 +36,11 @@ _UNET = [l for l in pix2pix().deconv_layers()]
 LAYERS = {"dcgan_d1": (_DCGAN["d1"], 16), "dcgan_d2": (_DCGAN["d2"], 16),
           "dcgan_d3": (_DCGAN["d3"], 16), "mde_up1": (_MDE["up1"], 1),
           **{f"pix2pix_{l.name}": (l, 32) for l in _UNET}}
+# The Cout < 128 layers, whose phases the kernel packs into its lanes,
+# at their benchmark cells' buckets (dcgan 256, mde 16, pix2pix 32).
+PACKED = {"dcgan_d2": (_DCGAN["d2"], 256), "dcgan_d3": (_DCGAN["d3"], 256),
+          **{f"mde_{n}": (_MDE[n], 16) for n in ("up1", "up2", "up3")},
+          **{f"pix2pix_{l.name}": (l, 32) for l in _UNET if l.cout < 128}}
 
 
 @pytest.fixture(scope="module")
@@ -60,10 +65,10 @@ def _compile_text(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-def _launch(name, dtype="", qout=False):
+def _launch(name, dtype="", qout=False, layers=LAYERS):
     """The fused launch the engine makes for one paper layer: operand
     shapes, the heuristic tile and the pad/crop/out_space arguments."""
-    layer, b = LAYERS[name]
+    layer, b = layers[name]
     k, s = layer.k, layer.s
     pads = same_deconv_pads(k, s)
     (kt, _), pk, (pi, _) = sd_geometry((k, k), (s, s))
@@ -133,6 +138,31 @@ def test_fused_int8_compiles(one_chip, chained):
             x, ws, s, scale=scale, act="relu",
             out_dtype="int8" if chained else None, interpret=False, **kw),
         x, ws, scale)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("name,dtype", [
+    *((n, "float32") for n in PACKED), ("dcgan_d3", "int8")])
+def test_packed_launches_compile(one_chip, name, dtype):
+    """Every phase-packed launch of the benchmark cells at its bucket and
+    heuristic tile, and a packed int8 launch with a static scale row."""
+    quant = dtype == "int8"
+    layer, b, kt, s, kw = _launch(name, dtype="int8" if quant else "",
+                                  layers=PACKED)
+    assert sd_conv.phase_packed(s * s, kw["tcout"])
+    x = jax.ShapeDtypeStruct((b, *layer.in_hw, layer.cin), dtype,
+                             sharding=one_chip)
+    ws = jax.ShapeDtypeStruct((kt, kt, layer.cin, layer.cout * s * s),
+                              dtype, sharding=one_chip)
+    extra = jax.ShapeDtypeStruct((1, layer.cout * s * s) if quant
+                                 else (layer.cout,), jnp.float32,
+                                 sharding=one_chip)
+    text = _compile_text(
+        lambda x, ws, extra: sd_conv.sd_fused_pallas(
+            x, ws, s, bias=None if quant else extra,
+            scale=extra if quant else None, act="relu", interpret=False,
+            **kw),
+        x, ws, extra)
     assert "tpu_custom_call" in text
 
 

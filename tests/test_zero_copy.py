@@ -190,6 +190,13 @@ def test_zero_copy_width_tiled_plan():
     ((1, 7, 2), (4, 2, 3), 3, (1, 0), 0),            # rank 1, asym
     ((2, 5, 6, 3), (4, 4, 3, 2), 2, 1, 0),           # rank 2
     ((1, 3, 4, 4, 2), (4, 4, 4, 2, 2), 2, 1, 1),     # rank 3
+    # Cout 3, 16 and 64 run phase-packed, Cout 128 one phase per lane
+    # tile: s 2 and 3, residual crops, output_padding, rank 1
+    ((2, 5, 6, 8), (3, 3, 8, 3), 2, ((1, 0), (0, 1)), 1),
+    ((1, 4, 5, 8), (5, 5, 8, 16), 3, 2, 2),
+    ((2, 5, 4, 8), (4, 4, 8, 64), 2, 1, 1),
+    ((1, 4, 4, 8), (4, 4, 8, 128), 2, 1, 1),
+    ((2, 9, 8), (7, 8, 16), 3, 3, 2),                 # rank 1
 ])
 def test_fused_backend_ranks(shape_x, shape_w, s, pad, op):
     rng = np.random.RandomState(3)
